@@ -12,8 +12,8 @@ Two levels are measured per case:
 
 interpret-mode wall-clock is NOT TPU performance; the derived columns
 (bytes/elements moved, tile plans, fallback counts, resolved policies) are
-the hardware-independent quantities -- they are what future TPU runs
-(``BPIM2COL_INTERPRET=0``) compare against.
+the hardware-independent quantities -- they are what TPU runs (where the
+kernels compile with Mosaic) compare against.
 
     PYTHONPATH=src python benchmarks/bench_kernels.py [--tiny] \
         [--json BENCH_kernels.json] [--compare BENCH_kernels.json]
@@ -81,6 +81,7 @@ import numpy as np
 
 sys.path.insert(0, "src")
 
+from repro.core.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core import bpim2col, im2col_ref, phase_decomp   # noqa: E402
 from repro.core.conv import (conv2d, conv2d_transpose,      # noqa: E402
                              resolve_policy, transpose_dims,
@@ -575,6 +576,7 @@ def main():
                          "plan was served from the persistent plan cache "
                          "(the CI smoke lane's warm second run)")
     args = ap.parse_args()
+    enable_compile_cache()
     updates = {}
     if args.autotune is not None:
         updates["autotune"] = args.autotune

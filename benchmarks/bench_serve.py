@@ -66,6 +66,7 @@ import numpy as np
 
 sys.path.insert(0, "src")
 
+from repro.core.compile_cache import enable_compile_cache  # noqa: E402
 from repro.configs import get_smoke_config            # noqa: E402
 from repro.models import model as M                   # noqa: E402
 from repro.serve.continuous import ContinuousEngine   # noqa: E402
@@ -294,6 +295,7 @@ def main():
                          "metrics JSONL to PATH")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.trace or args.metrics:
         from repro.core.config import config
         config.update(telemetry=True, trace_path=args.trace,

@@ -12,6 +12,8 @@ sys.path.insert(0, "src")
 
 
 def main() -> None:
+    from repro.core.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bench_table2, bench_table3, bench_fig6,
                             bench_fig7, bench_fig8, bench_kernels, roofline)
 

@@ -18,9 +18,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import bpim2col as bp
+from repro.core.compile_cache import enable_compile_cache
 from repro.core import im2col_ref as ref
 from repro.core import phase_decomp as ph
 from repro.core.im2col_ref import ConvDims
+
+enable_compile_cache()
 
 # A conv layer from the paper's Table II (scaled-down channels for CPU).
 d = ConvDims(B=2, C=8, H_i=28, W_i=28, N=16, K_h=3, K_w=3, S=2, P_h=1, P_w=1)

@@ -30,6 +30,7 @@ sys.path.insert(0, "src")
 import jax
 import numpy as np
 
+from repro.core.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.optim import adamw
 from repro.train import make_train_step
@@ -63,6 +64,7 @@ def main():
     ap.add_argument("--mse-floor", type=float, default=0.05,
                     help="final reconstruction MSE must fall below this")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = M.AutoencoderConfig(c_in=3, widths=(16, 32), k=3,
                               conv_policy=args.policy)

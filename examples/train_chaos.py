@@ -42,6 +42,7 @@ from train_cnn_bp import init_params, make_model, synthetic_task
 
 from repro import obs
 from repro.core import conv
+from repro.core.compile_cache import enable_compile_cache
 from repro.core.config import config
 from repro.core.convspec import ConvSpec
 from repro.ft import inject
@@ -86,6 +87,7 @@ def main():
                     help="enable telemetry and stream per-step metrics "
                          "JSONL to PATH")
     args = ap.parse_args()
+    enable_compile_cache()
     assert args.steps >= 8, "the fault timeline needs at least 8 steps"
 
     conv.QUARANTINE_PROBE_AFTER = 2   # arc: fail@3, skip@4-5, probe@6

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.compile_cache import enable_compile_cache
 from repro.models import layers as L
 
 
@@ -111,6 +112,7 @@ def main():
                     help="enable telemetry and stream per-step metrics "
                          "JSONL to PATH")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.autotune is not None or args.plan_cache_dir is not None \
             or args.fault_spec is not None or args.trace is not None \
             or args.metrics is not None:

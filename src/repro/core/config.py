@@ -76,6 +76,20 @@ def _check_bool(v: Any) -> bool:
     return v
 
 
+def _check_optional_bool(v: Any) -> bool | None:
+    if v is not None and not isinstance(v, bool):
+        raise ValueError(f"expected a bool or None, got {v!r}")
+    return v
+
+
+def _backend_interpret() -> bool:
+    """Interpret Pallas kernels unless jax's default backend is a TPU.
+    Asked on the first read of ``interpret``, never while a module is
+    imported: asking initializes jax's backends."""
+    import jax
+    return jax.default_backend() != "tpu"
+
+
 def _check_optional_str(v: Any) -> str | None:
     if v is not None and not isinstance(v, str):
         raise ValueError(f"expected a str or None, got {v!r}")
@@ -111,9 +125,11 @@ class _Field:
 #: field name -> spec.  The env vars are the DEPRECATED aliases; the field
 #: is the source of truth after import.
 FIELDS: dict[str, _Field] = {
-    # Pallas kernels: interpret mode (CPU) vs Mosaic compile (real TPU).
-    "interpret": _Field("BPIM2COL_INTERPRET", True, _parse_bool,
-                        _check_bool, plan_affecting=True),
+    # Pallas kernels: interpret mode (CPU) vs Mosaic compile (TPU).  None
+    # resolves from the backend on first read: False on a TPU, True
+    # elsewhere (kernels/tap_gemm.py refuses to interpret on a TPU).
+    "interpret": _Field("BPIM2COL_INTERPRET", None, _parse_bool,
+                        _check_optional_bool, plan_affecting=True),
     # Tile-plan search budget: per-grid-step VMEM footprint ceiling.
     "vmem_budget_bytes": _Field("REPRO_VMEM_BUDGET_BYTES", 14 * 1024 * 1024,
                                 int, _check_positive_int("vmem_budget_bytes"),
@@ -266,6 +282,8 @@ class GlobalConfig:
                 _sync_fault_injector()
             if name in _OBS_FIELDS:
                 _sync_obs()
+        if name == "interpret" and self._values[name] is None:
+            self._values[name] = _backend_interpret()
         return self._values[name]
 
     def snapshot(self) -> dict[str, Any]:

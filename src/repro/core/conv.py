@@ -94,6 +94,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import bpim2col, im2col_ref, phase_decomp
+from repro.core.config import config
 from repro.obs import events as obs_events
 from repro.obs import trace as obs_trace
 from repro.core.convspec import (AUTO, ConvSpec, ConvTransposeSpec,
@@ -607,7 +608,6 @@ def _runtime_chain(name: str, d: ConvDims) -> list[str]:
 def _poison_plan_entry(pass_name: str, transposed: bool, d: ConvDims) -> None:
     """Poison-mark the plan-cache entry that fed a crashing pallas launch
     (best effort -- poisoning must never mask the degradation itself)."""
-    from repro.core.config import config
     if config.autotune == "off":
         return
     role = _TRANSPOSE_ROLE[pass_name] if transposed else pass_name
@@ -620,10 +620,15 @@ def _poison_plan_entry(pass_name: str, transposed: bool, d: ConvDims) -> None:
 
 def _execute(pass_name: str, requested: str, d: ConvDims, transposed: bool,
              run: Callable):
-    """Resolve one conv pass and execute it with runtime degradation.
+    """Resolve one conv pass and execute it, degrading at run time only
+    while a fault is armed.
 
-    ``run(engine)`` performs the pass.  An exception from the engine
-    re-dispatches down the capability-ordered fallback chain: the failure
+    ``run(engine)`` performs the pass.  With no fault armed
+    (``config.fault_spec`` unset) an exception from the engine propagates:
+    a kernel that fails to trace, lower or run is a bug to surface, and
+    serving the pass from another engine would hide which engine ran.
+    With a fault armed, an exception re-dispatches down the
+    capability-ordered fallback chain: the failure
     is recorded (``dispatch_events`` gains ``"pass:failed->survivor"``,
     :func:`runtime_failures` keeps the exception class), the failing
     engine is QUARANTINED for this (pass, geometry) -- subsequent
@@ -657,6 +662,8 @@ def _execute(pass_name: str, requested: str, d: ConvDims, transposed: bool,
             with obs_trace.dispatch_span(pkey, cand, d):
                 out = run(ENGINES[cand])
         except Exception as e:
+            if not config.fault_spec:
+                raise
             if first_exc is None:
                 first_exc = e
             if cand != "lax":

@@ -46,7 +46,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 import math
 
@@ -448,12 +447,12 @@ def _fwd_regular(x, w, spec: ConvSpec, policy, plan: ConvShardPlan):
             y = jax.lax.psum(y, plan.cin)
         return y
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=plan.mesh,
         in_specs=(P(plan.batch_spec, plan.cin, plan.h, plan.w),
                   P(plan.cout, plan.cin, None, None)),
         out_specs=P(plan.batch_spec, plan.cout, plan.h, plan.w),
-        check_rep=False)(x, w)
+        check_vma=False)(x, w)
 
 
 def _dgrad_regular(dy, w, x_shape, spec: ConvSpec, policy,
@@ -476,12 +475,12 @@ def _dgrad_regular(dy, w, x_shape, spec: ConvSpec, policy,
             dx_ext = jax.lax.psum(dx_ext, plan.cout)
         return _scatter_spatial(dx_ext, plan, blk_h, blk_w)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=plan.mesh,
         in_specs=(P(plan.batch_spec, plan.cout, plan.h, plan.w),
                   P(plan.cout, plan.cin, None, None)),
         out_specs=P(plan.batch_spec, plan.cin, plan.h, plan.w),
-        check_rep=False)(dy, w)
+        check_vma=False)(dy, w)
 
 
 def _wgrad_regular(x, dy, w_shape, spec: ConvSpec, policy,
@@ -501,12 +500,12 @@ def _wgrad_regular(x, dy, w_shape, spec: ConvSpec, policy,
             dw = jax.lax.psum(dw, reduce_axes)
         return dw
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=plan.mesh,
         in_specs=(P(plan.batch_spec, plan.cin, plan.h, plan.w),
                   P(plan.batch_spec, plan.cout, plan.h, plan.w)),
         out_specs=P(plan.cout, plan.cin, None, None),
-        check_rep=False)(x, dy)
+        check_vma=False)(x, dy)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
@@ -550,12 +549,12 @@ def _t_fwd(x, w, spec: ConvTransposeSpec, policy, plan: ConvShardPlan,
             y_ext = jax.lax.psum(y_ext, plan.cin)
         return _scatter_spatial(y_ext, plan, blk_h, blk_w)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=plan.mesh,
         in_specs=(P(plan.batch_spec, plan.cin, plan.h, plan.w),
                   P(plan.cin, plan.cout, None, None)),
         out_specs=P(plan.batch_spec, plan.cout, plan.h, plan.w),
-        check_rep=False)(x, w)
+        check_vma=False)(x, w)
 
 
 def _t_dgrad(dy, w, x_shape, spec: ConvTransposeSpec, policy,
@@ -577,12 +576,12 @@ def _t_dgrad(dy, w, x_shape, spec: ConvTransposeSpec, policy,
             dx = jax.lax.psum(dx, plan.cout)
         return dx
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=plan.mesh,
         in_specs=(P(plan.batch_spec, plan.cout, plan.h, plan.w),
                   P(plan.cin, plan.cout, None, None)),
         out_specs=P(plan.batch_spec, plan.cin, plan.h, plan.w),
-        check_rep=False)(dy, w)
+        check_vma=False)(dy, w)
 
 
 def _t_wgrad(dy, x, x_shape, w_shape, spec: ConvTransposeSpec, policy,
@@ -606,12 +605,12 @@ def _t_wgrad(dy, x, x_shape, w_shape, spec: ConvTransposeSpec, policy,
             dw = jax.lax.psum(dw, reduce_axes)
         return dw
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=plan.mesh,
         in_specs=(P(plan.batch_spec, plan.cout, plan.h, plan.w),
                   P(plan.batch_spec, plan.cin, plan.h, plan.w)),
         out_specs=P(plan.cin, plan.cout, None, None),
-        check_rep=False)(dy, x)
+        check_vma=False)(dy, x)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))

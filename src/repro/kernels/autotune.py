@@ -17,7 +17,7 @@ enabled, the planners route through :func:`tuned_plan`:
          mode "cached": never time -- persisted winners when present,
          the analytic plan otherwise.
 
-The cache file lives next to jax's compilation cache by default
+The cache file lives inside jax's compilation cache directory by default
 (``config.plan_cache_dir`` overrides), is written atomically
 (tmp + ``os.replace``), and tolerates corrupt files and stale entries:
 a bad entry re-tunes, it never crashes.  Timing is interpret-mode aware:
@@ -45,15 +45,16 @@ import warnings
 import jax
 import jax.numpy as jnp
 
+from repro.core.compile_cache import compile_cache_dir
 from repro.core.config import config
 from repro.core.im2col_ref import ConvDims
 from repro.ft.inject import InjectedFault, fault_point
 from repro.kernels import ops
 from repro.obs import trace as obs_trace
 
-#: bump when the key layout or entry payload changes; older files are
-#: ignored wholesale (equivalent to a cold cache).
-CACHE_SCHEMA = 1
+#: bump when the key layout, the entry payload or the meaning of a tile
+#: changes; older files are ignored wholesale (equivalent to a cold cache).
+CACHE_SCHEMA = 2
 
 _CACHE_FILE = "plan_cache.json"
 
@@ -72,13 +73,10 @@ def clear_memo() -> None:
 
 def default_cache_dir() -> str:
     """``config.plan_cache_dir`` when set, else a ``repro_plan_cache``
-    directory next to jax's compilation cache."""
+    directory inside jax's compilation cache directory."""
     if config.plan_cache_dir is not None:
         return config.plan_cache_dir
-    base = getattr(jax.config, "jax_compilation_cache_dir", None)
-    if not base:
-        base = os.path.join(os.path.expanduser("~"), ".cache", "jax")
-    return os.path.join(base, "repro_plan_cache")
+    return os.path.join(compile_cache_dir(), "repro_plan_cache")
 
 
 def cache_path() -> str:

@@ -40,12 +40,12 @@ candidates timed on device, persisted in an on-disk plan cache.  The
 plans exactly as they consult analytic ones, because they all go through
 these three entry points.
 
-``repro.config.interpret`` defaults to True because this container is
-CPU-only; on real TPU hardware set ``BPIM2COL_INTERPRET=0`` in the
-environment (or ``repro.config.update(interpret=False)``) to compile the
-kernels with Mosaic instead -- no code edit required.  The pre-config
-module globals ``INTERPRET`` / ``VMEM_BUDGET_BYTES`` remain readable and
-assignable as deprecated aliases of the config fields.
+``repro.config.interpret`` is worked out from the backend on first use:
+the kernels are compiled with Mosaic on a TPU and run in the Pallas
+interpreter on the CPU (where the test-suite runs them).  On a TPU an
+interpreted launch is an error (``tap_gemm.resolve_interpret``).  The
+pre-config module globals ``INTERPRET`` / ``VMEM_BUDGET_BYTES`` remain
+readable and assignable as deprecated aliases of the config fields.
 """
 
 from __future__ import annotations
@@ -55,6 +55,7 @@ import functools
 import sys
 import types
 import warnings
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -65,9 +66,12 @@ from repro.core import phase_decomp
 from repro.ft.inject import fault_point
 from repro.kernels import tap_gemm as tg
 from repro.obs import events as obs_events
-from repro.kernels.tap_gemm import _cdiv, _taps_halo
+from repro.kernels.tap_gemm import LANE, SUBLANE, _cdiv, _round_up, _taps_halo
 
-_ELEM_BYTES = 4            # budget in f32 elements (worst case)
+#: most GEMM rows (``th * tw``) one grid step may hold.  Mosaic unrolls each
+#: tap's dot over the tile's vregs, so this bounds compile time and the
+#: live values of the tap loop; VMEM alone would allow far larger tiles.
+MAX_TILE_ROWS = 1024
 
 #: planned-vs-fallback outcomes, one event per unique (ConvDims, budget)
 #: planner invocation (memoized calls do not re-count).
@@ -125,12 +129,15 @@ def _pad_to(x, n: int, axis: int = -1):
     return jnp.pad(x, pads)
 
 
-def _channel_tile(c: int) -> tuple[int, int]:
-    """(padded_c, tile): no padding below 128 channels, 128-tiles above."""
-    if c <= 128:
+def _channel_tile(c: int, contraction: bool) -> tuple[int, int]:
+    """(padded_c, tile) of one channel dim.  A contraction dim is the lane
+    dim of the DMA'd source window, which Mosaic copies in whole 128-lane
+    tiles, so it is padded to a multiple of 128 (zero lanes add nothing to
+    the GEMM); an output dim up to 128 stays whole, wider ones are padded
+    and tiled by 128."""
+    if c <= LANE and not contraction:
         return c, c
-    cp = -(-c // 128) * 128
-    return cp, 128
+    return _round_up(c, LANE), LANE
 
 
 def _phase_split(xp: jax.Array, s: tuple[int, int]) -> jax.Array:
@@ -162,70 +169,47 @@ def _phase_unsplit(planes: jax.Array, s: tuple[int, int],
 # ---------------------------------------------------------------------------
 
 def _spatial_candidates(oh: int, ow: int):
-    """Full plane first, then halve the larger spatial dim (1x, 2x, 4x, ...
-    splits) down to a 1x1 tile."""
-    th, tw = oh, ow
+    """Output tiles in search order: the whole (8-padded) width first,
+    halving rows -- an untiled leading dim -- down to one, then halving
+    the W tile in whole sublanes.  Tiles over :data:`MAX_TILE_ROWS` GEMM
+    rows are skipped."""
+    th, tw = oh, _round_up(ow, SUBLANE)
     while True:
-        yield th, tw
-        if th <= 1 and tw <= 1:
-            return
-        if th >= tw and th > 1:
+        if th * tw <= MAX_TILE_ROWS:
+            yield th, tw
+        if th > 1:
             th = _cdiv(th, 2)
+        elif tw > SUBLANE:
+            tw = _round_up(_cdiv(tw, 2), SUBLANE)
         else:
-            tw = _cdiv(tw, 2)
-
-
-def _channel_candidates(cin_pad: int, cout_pad: int):
-    """Full (<=128) channel tiles first, then halve both while the halves
-    still divide the padded channel counts."""
-    ci, co = min(cin_pad, 128), min(cout_pad, 128)
-    yield ci, co
-    while ci > 1 or co > 1:
-        nci = ci // 2 if (ci > 1 and ci % 2 == 0
-                          and cin_pad % (ci // 2) == 0) else ci
-        nco = co // 2 if (co > 1 and co % 2 == 0
-                          and cout_pad % (co // 2) == 0) else co
-        if (nci, nco) == (ci, co):
             return
-        ci, co = nci, nco
-        yield ci, co
 
 
-def _search_tiles(oh, ow, cin_pad, cout_pad, cost_fn, budget):
-    """First candidate whose cost fits: spatial splits are exhausted before
-    channel tiles shrink, so large planes tile spatially at full MXU width.
-    Returns (th, tw, n_th, n_tw, cin_t, cout_t, bytes, fits)."""
+def _search_tiles(oh, ow, cost_fn, budget):
+    """First spatial tile whose footprint fits, largest first.
+    Returns (th, tw, n_th, n_tw, bytes, fits)."""
     last = None
-    for cin_t, cout_t in _channel_candidates(cin_pad, cout_pad):
-        for th, tw in _spatial_candidates(oh, ow):
-            bytes_needed = cost_fn(th, tw, cin_t, cout_t)
-            last = (th, tw, _cdiv(oh, th), _cdiv(ow, tw), cin_t, cout_t,
-                    bytes_needed)
-            if bytes_needed <= budget:
-                return (*last, True)
+    for th, tw in _spatial_candidates(oh, ow):
+        bytes_needed = cost_fn(th, tw)
+        last = (th, tw, _cdiv(oh, th), _cdiv(ow, tw), bytes_needed)
+        if bytes_needed <= budget:
+            return (*last, True)
     return (*last, False)
 
 
-def _search_tiles_topk(oh, ow, cin_pad, cout_pad, cost_fn, budget, k):
-    """Up to ``k`` distinct FITTING candidates in analytic search order (the
-    first element is exactly what :func:`_search_tiles` returns when it
-    fits): the autotuner's shortlist.  The analytic order ranks by bytes
-    moved, so the shortlist is "the analytically best plan plus the next
-    finer tilings" -- the region where the analytic model most often
-    mispredicts real hardware."""
-    out, seen = [], set()
-    for cin_t, cout_t in _channel_candidates(cin_pad, cout_pad):
-        for th, tw in _spatial_candidates(oh, ow):
-            cand = (th, tw, cin_t, cout_t)
-            if cand in seen:
-                continue
-            seen.add(cand)
-            bytes_needed = cost_fn(th, tw, cin_t, cout_t)
-            if bytes_needed <= budget:
-                out.append((th, tw, _cdiv(oh, th), _cdiv(ow, tw),
-                            cin_t, cout_t, bytes_needed))
-                if len(out) >= k:
-                    return out
+def _search_tiles_topk(oh, ow, cost_fn, budget, k):
+    """Up to ``k`` FITTING tiles in search order (the first is exactly
+    what :func:`_search_tiles` returns when it fits): the autotuner's
+    shortlist -- the analytically largest tile plus the next finer ones,
+    the region where the footprint model most often mispredicts real
+    hardware."""
+    out = []
+    for th, tw in _spatial_candidates(oh, ow):
+        bytes_needed = cost_fn(th, tw)
+        if bytes_needed <= budget:
+            out.append((th, tw, _cdiv(oh, th), _cdiv(ow, tw), bytes_needed))
+            if len(out) >= k:
+                break
     return out
 
 
@@ -312,42 +296,61 @@ def _budget_or_default(budget: int | None) -> int:
     return config.vmem_budget_bytes if budget is None else budget
 
 
-def _forward_geom(d: ConvDims):
-    """(cin_pad, cout_pad, taps, halo_h, halo_w, cost_fn) of a forward."""
-    cin_p, _ = _channel_tile(d.C)
-    cout_p, _ = _channel_tile(d.N)
-    taps = _forward_taps(d)
-    halo_h, halo_w = _taps_halo(taps)
-    s2 = d.s_h * d.s_w
+@dataclasses.dataclass(frozen=True)
+class _Geom:
+    """Everything one planning problem fixes before a spatial tile is
+    chosen: the output plane the tiles cover, the channel padding and
+    tiles, the tap table and halo, and the VMEM footprint of a tile."""
+    oh: int
+    ow: int
+    cin_pad: int
+    cin_tile: int
+    cout_pad: int
+    cout_tile: int
+    taps: tuple
+    halo_h: int
+    halo_w: int
+    cost: Callable[[int, int], int]       # (th, tw) -> VMEM bytes
+    phase: tuple | None = None            # _input_grad_geom, input_grad only
 
-    def cost(th, tw, cit, cot):
-        return _ELEM_BYTES * (s2 * (th + halo_h) * (tw + halo_w) * cit
-                              + len(taps) * cit * cot
-                              + 2 * th * tw * cot)
-
-    return cin_p, cout_p, taps, halo_h, halo_w, cost
+    def plan(self, th, tw, n_th, n_tw, bytes_needed, fits=True) -> TilePlan:
+        return TilePlan(fits, self.cin_pad, self.cin_tile, self.cout_pad,
+                        self.cout_tile, self.taps, th, tw, n_th, n_tw,
+                        self.halo_h, self.halo_w, bytes_needed)
 
 
-def _weight_grad_geom(d: ConvDims):
-    cin_p, _ = _channel_tile(d.C)
-    cout_p, _ = _channel_tile(d.N)
-    taps = _forward_taps(d)
-    halo_h, halo_w = _taps_halo(taps)
-    s2 = d.s_h * d.s_w
-
-    def cost(th, tw, cit, cot):
-        return _ELEM_BYTES * (s2 * (th + halo_h) * (tw + halo_w) * cit
-                              + th * tw * cot
-                              + 2 * len(taps) * cit * cot)
-
-    return cin_p, cout_p, taps, halo_h, halo_w, cost
+def _geom(role: str, d: ConvDims) -> _Geom:
+    """The tiling problem of one pass.  Forward and weight grad contract
+    over C (input channels) into N; the fused input grad contracts over N
+    into C across all stride phases."""
+    if role in ("forward", "weight_grad"):
+        taps = _forward_taps(d)
+        halo_h, halo_w = _taps_halo(taps)
+        cin_p, cit = _channel_tile(d.C, contraction=True)
+        cout_p, cot = _channel_tile(d.N, contraction=False)
+        vmem = (tg.tap_gemm_vmem if role == "forward"
+                else tg.tap_wgrad_vmem)
+        planes = d.s_h * d.s_w
+        return _Geom(d.H_o, d.W_o, cin_p, cit, cout_p, cot, taps,
+                     halo_h, halo_w,
+                     lambda th, tw: vmem(planes, len(taps), th, tw, halo_h,
+                                         halo_w, cit, cot))
+    if role == "input_grad":
+        phase = _input_grad_geom(d)
+        n_qh, n_qw, _, _, t_max, _, _, halo_h, halo_w = phase
+        cin_p, cit = _channel_tile(d.N, contraction=True)
+        cout_p, cot = _channel_tile(d.C, contraction=False)
+        return _Geom(n_qh, n_qw, cin_p, cit, cout_p, cot, (), halo_h, halo_w,
+                     lambda th, tw: tg.tap_gemm_vmem(
+                         1, t_max, th, tw, halo_h, halo_w, cit, cot),
+                     phase)
+    raise ValueError(f"unknown plan role {role!r}; roles: {PLAN_ROLES}")
 
 
 @functools.lru_cache(maxsize=4096)
 def _input_grad_geom(d: ConvDims):
     """The fused-phase geometry shared by every input-grad tile candidate:
-    (cin_pad, cout_pad, n_qh, n_qw, g_lo_h, g_lo_w, t_max, specs, taps_all,
-    halo_h, halo_w).
+    (n_qh, n_qw, g_lo_h, g_lo_w, t_max, specs, taps_all, halo_h, halo_w).
 
     Row and column tap tables are independent: each axis runs its own
     ``phase_geometry`` under its own stride, and a kernel dilation drops
@@ -355,8 +358,6 @@ def _input_grad_geom(d: ConvDims):
     (effective tap ``c + m*s`` is real iff it is a multiple of ``D``)."""
     s_h, s_w = d.s_h, d.s_w
     a_h, a_w = d.K_h - 1 - d.P_h, d.K_w - 1 - d.P_w
-    cin_p, _ = _channel_tile(d.N)      # contraction dim = N
-    cout_p, _ = _channel_tile(d.C)
     n_qh, n_qw = _cdiv(d.H_i, s_h), _cdiv(d.W_i, s_w)
     geo_h = [phase_decomp.phase_geometry(r, a_h, s_h, d.K_h, d.H_i, d.H_o)
              for r in range(s_h)]
@@ -407,20 +408,12 @@ def _input_grad_geom(d: ConvDims):
             t_max = max(t_max, len(th_) * len(tw_))
             halo_h = max(halo_h, sh + th_[-1][0])
             halo_w = max(halo_w, sw + tw_[-1][0])
-    return (cin_p, cout_p, n_qh, n_qw, g_lo_h, g_lo_w, t_max,
-            tuple(specs), tuple(taps_all), halo_h, halo_w)
-
-
-def _input_grad_cost(t_max: int, halo_h: int, halo_w: int):
-    def cost(th, tw, cit, cot):
-        return _ELEM_BYTES * ((th + halo_h) * (tw + halo_w) * cit
-                              + t_max * cit * cot
-                              + 2 * th * tw * cot)
-    return cost
+    return (n_qh, n_qw, g_lo_h, g_lo_w, t_max, tuple(specs),
+            tuple(taps_all), halo_h, halo_w)
 
 
 def _phase_plan_of(d: ConvDims, geom, tile: TilePlan) -> PhasePlan:
-    _, _, n_qh, n_qw, g_lo_h, g_lo_w, t_max, specs, taps_all, _, _ = geom
+    n_qh, n_qw, g_lo_h, g_lo_w, t_max, specs, taps_all, _, _ = geom
     return PhasePlan(n_qh, n_qw, g_lo_h, g_lo_w, t_max, specs, taps_all,
                      tile)
 
@@ -438,6 +431,13 @@ def _autotuned(role: str, d: ConvDims, budget: int, analytic):
     return autotune.tuned_plan(role, d, budget, analytic)
 
 
+def _analytic_plan(role: str, d: ConvDims, budget: int) -> TilePlan:
+    g = _geom(role, d)
+    *tile, fits = _search_tiles(g.oh, g.ow, g.cost, budget)
+    _count_event(f"{role}_pallas" if fits else f"{role}_fallback")
+    return g.plan(*tile, fits=fits)
+
+
 def forward_plan(d: ConvDims, budget: int | None = None) -> TilePlan:
     d, budget = _canonical(d), _budget_or_default(budget)
     return _autotuned("forward", d, budget, _forward_plan(d, budget))
@@ -445,12 +445,7 @@ def forward_plan(d: ConvDims, budget: int | None = None) -> TilePlan:
 
 @functools.lru_cache(maxsize=4096)
 def _forward_plan(d: ConvDims, budget: int) -> TilePlan:
-    cin_p, cout_p, taps, halo_h, halo_w, cost = _forward_geom(d)
-    th, tw, n_th, n_tw, cit, cot, bytes_needed, fits = _search_tiles(
-        d.H_o, d.W_o, cin_p, cout_p, cost, budget)
-    _count_event("forward_pallas" if fits else "forward_fallback")
-    return TilePlan(fits, cin_p, cit, cout_p, cot, taps, th, tw, n_th, n_tw,
-                    halo_h, halo_w, bytes_needed)
+    return _analytic_plan("forward", d, budget)
 
 
 def weight_grad_plan(d: ConvDims, budget: int | None = None) -> TilePlan:
@@ -460,12 +455,7 @@ def weight_grad_plan(d: ConvDims, budget: int | None = None) -> TilePlan:
 
 @functools.lru_cache(maxsize=4096)
 def _weight_grad_plan(d: ConvDims, budget: int) -> TilePlan:
-    cin_p, cout_p, taps, halo_h, halo_w, cost = _weight_grad_geom(d)
-    th, tw, n_th, n_tw, cit, cot, bytes_needed, fits = _search_tiles(
-        d.H_o, d.W_o, cin_p, cout_p, cost, budget)
-    _count_event("weight_grad_pallas" if fits else "weight_grad_fallback")
-    return TilePlan(fits, cin_p, cit, cout_p, cot, taps, th, tw, n_th, n_tw,
-                    halo_h, halo_w, bytes_needed)
+    return _analytic_plan("weight_grad", d, budget)
 
 
 def input_grad_plan(d: ConvDims,
@@ -479,17 +469,10 @@ def _input_grad_plan(d: ConvDims, budget: int) -> PhasePlan | None:
     """Single fused dispatch plan for all s_h*s_w output stride phases, or
     None only when even the minimal tiling exceeds the budget (the op then
     falls back to the jnp phase decomposition)."""
-    geom = _input_grad_geom(d)
-    cin_p, cout_p, n_qh, n_qw, _, _, t_max, _, _, halo_h, halo_w = geom
-    th, tw, n_th, n_tw, cit, cot, bytes_needed, fits = _search_tiles(
-        n_qh, n_qw, cin_p, cout_p,
-        _input_grad_cost(t_max, halo_h, halo_w), budget)
-    _count_event("input_grad_pallas" if fits else "input_grad_fallback")
-    if not fits:
+    tile = _analytic_plan("input_grad", d, budget)
+    if not tile.fits:
         return None
-    tile = TilePlan(True, cin_p, cit, cout_p, cot, (), th, tw, n_th, n_tw,
-                    halo_h, halo_w, bytes_needed)
-    return _phase_plan_of(d, geom, tile)
+    return _phase_plan_of(d, _input_grad_geom(d), tile)
 
 
 #: the three tap-GEMM pass roles the planners (and the autotuner) speak.
@@ -544,24 +527,11 @@ def plan_candidates(role: str, d: ConvDims, budget: int | None = None,
     Pure and unmemoized; records no plan events."""
     d, budget = _canonical(d), _budget_or_default(budget)
     k = config.autotune_top_k if k is None else k
-    if role == "forward":
-        cin_p, cout_p, taps, halo_h, halo_w, cost = _forward_geom(d)
-        oh, ow = d.H_o, d.W_o
-    elif role == "weight_grad":
-        cin_p, cout_p, taps, halo_h, halo_w, cost = _weight_grad_geom(d)
-        oh, ow = d.H_o, d.W_o
-    elif role == "input_grad":
-        geom = _input_grad_geom(d)
-        cin_p, cout_p, oh, ow, _, _, t_max, _, _, halo_h, halo_w = geom
-        taps, cost = (), _input_grad_cost(t_max, halo_h, halo_w)
-    else:
-        raise ValueError(f"unknown plan role {role!r}; roles: {PLAN_ROLES}")
-    tiles = _search_tiles_topk(oh, ow, cin_p, cout_p, cost, budget, k)
-    plans = [TilePlan(True, cin_p, cit, cout_p, cot, taps, th, tw,
-                      n_th, n_tw, halo_h, halo_w, bytes_needed)
-             for th, tw, n_th, n_tw, cit, cot, bytes_needed in tiles]
+    g = _geom(role, d)
+    plans = [g.plan(*t)
+             for t in _search_tiles_topk(g.oh, g.ow, g.cost, budget, k)]
     if role == "input_grad":
-        return [_phase_plan_of(d, geom, t) for t in plans]
+        return [_phase_plan_of(d, g.phase, t) for t in plans]
     return plans
 
 
@@ -569,39 +539,26 @@ def plan_from_tile(role: str, d: ConvDims, budget: int | None,
                    tile) -> TilePlan | PhasePlan | None:
     """Rebuild a dispatchable plan from a PERSISTED candidate identity
     ``(oh_tile, ow_tile, cin_tile, cout_tile)``, revalidating it against
-    the current geometry and budget.  Returns None when the tile is no
-    longer valid (plan-cache entry gone stale: code changed the geometry,
-    the budget shrank, or the entry is garbage) -- the caller re-tunes."""
+    the current geometry, tiling rules and budget.  Returns None when the
+    tile is no longer valid (plan-cache entry gone stale: code changed the
+    geometry, the budget shrank, or the entry is garbage) -- the caller
+    re-tunes."""
     d, budget = _canonical(d), _budget_or_default(budget)
+    g = _geom(role, d)
     try:
         th, tw, cit, cot = (int(v) for v in tile)
     except (TypeError, ValueError):
         return None
-    if role == "forward":
-        cin_p, cout_p, taps, halo_h, halo_w, cost = _forward_geom(d)
-        oh, ow = d.H_o, d.W_o
-    elif role == "weight_grad":
-        cin_p, cout_p, taps, halo_h, halo_w, cost = _weight_grad_geom(d)
-        oh, ow = d.H_o, d.W_o
-    elif role == "input_grad":
-        geom = _input_grad_geom(d)
-        cin_p, cout_p, oh, ow, _, _, t_max, _, _, halo_h, halo_w = geom
-        taps, cost = (), _input_grad_cost(t_max, halo_h, halo_w)
-    else:
-        raise ValueError(f"unknown plan role {role!r}; roles: {PLAN_ROLES}")
-    if not (1 <= th <= oh and 1 <= tw <= ow):
+    if (th, tw) not in set(_spatial_candidates(g.oh, g.ow)):
         return None
-    if not (1 <= cit <= cin_p and 1 <= cot <= cout_p
-            and cin_p % cit == 0 and cout_p % cot == 0):
+    if (cit, cot) != (g.cin_tile, g.cout_tile):
         return None
-    bytes_needed = cost(th, tw, cit, cot)
+    bytes_needed = g.cost(th, tw)
     if bytes_needed > budget:
         return None
-    plan = TilePlan(True, cin_p, cit, cout_p, cot, taps, th, tw,
-                    _cdiv(oh, th), _cdiv(ow, tw), halo_h, halo_w,
-                    bytes_needed)
+    plan = g.plan(th, tw, _cdiv(g.oh, th), _cdiv(g.ow, tw), bytes_needed)
     if role == "input_grad":
-        return _phase_plan_of(d, geom, plan)
+        return _phase_plan_of(d, g.phase, plan)
     return plan
 
 
@@ -688,7 +645,8 @@ def conv2d_forward(x: jax.Array, w: jax.Array, d: ConvDims,
     y = tg.tap_gemm(src, wt, plan.taps, d.H_o, d.W_o,
                     cin_tile=plan.cin_tile, cout_tile=plan.cout_tile,
                     oh_tile=plan.oh_tile, ow_tile=plan.ow_tile,
-                    out_dtype=x.dtype, interpret=config.interpret)
+                    out_dtype=x.dtype,
+                    vmem_limit_bytes=config.vmem_budget_bytes)
     return _from_nhwc(y[..., :d.N])
 
 
@@ -732,7 +690,7 @@ def conv2d_input_grad(dy: jax.Array, w: jax.Array, d: ConvDims,
         cin_tile=tile.cin_tile, cout_tile=tile.cout_tile,
         oh_tile=tile.oh_tile, ow_tile=tile.ow_tile,
         out_dtype=dy.dtype,
-        interpret=config.interpret)                   # (sh*sw, B, qh, qw, C)
+        vmem_limit_bytes=config.vmem_budget_bytes)    # (sh*sw, B, qh, qw, C)
     di = _phase_unsplit(out[..., :d.C], (d.s_h, d.s_w), d.H_i, d.W_i)
     return _from_nhwc(di)
 
@@ -761,7 +719,7 @@ def conv2d_weight_grad(x: jax.Array, dy: jax.Array, d: ConvDims,
     dw = tg.tap_wgrad(src, dyn, plan.taps, d.H_o, d.W_o,
                       cin_tile=plan.cin_tile, cout_tile=plan.cout_tile,
                       oh_tile=plan.oh_tile, ow_tile=plan.ow_tile,
-                      interpret=config.interpret)
+                      vmem_limit_bytes=config.vmem_budget_bytes)
     dw = dw[:, :d.C, :d.N].reshape(d.k_taps_h, d.k_taps_w, d.C, d.N)
     return dw.transpose(3, 2, 0, 1).astype(x.dtype)
 

@@ -12,13 +12,22 @@ from __future__ import annotations
 import jax
 
 
+def auto_mesh(shape, axes):
+    """A mesh whose axes are ``Auto``: shardings propagate through the
+    compiler as before, instead of jax's default ``Explicit`` axes, under
+    which gathers from a sharded table (``jnp.take`` on an embedding)
+    demand an explicit ``out_sharding``."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Whatever this host has (CPU smoke tests: 1 device)."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return auto_mesh((n, 1), ("data", "model"))

@@ -19,6 +19,7 @@ import time
 import jax
 import numpy as np
 
+from repro.core.compile_cache import enable_compile_cache
 from repro.configs import get_smoke_config
 from repro.models import model as M
 from repro.serve.continuous import ContinuousEngine
@@ -49,6 +50,7 @@ def main(argv=None):
                          "(e.g. 'auto', 'bp_phase', or "
                          "'fwd=...,dgrad=...,wgrad=...')")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch)
     model = M.build_model(cfg)

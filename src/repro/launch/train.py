@@ -32,6 +32,7 @@ from repro.ft.failures import (GuardState, HeartbeatTable, StragglerDetector,
 from repro.models import model as M
 from repro.optim import adamw
 from repro.train import train_step as TS
+from repro.core.compile_cache import enable_compile_cache
 from repro import obs
 
 
@@ -53,7 +54,20 @@ def resolve_conv_policy_args(conv_policy: str | None,
     return conv_policy
 
 
-def main(argv=None):
+def _with_guard_streak(opt_state, guard: bool):
+    """The in-graph guard carries its bad-step streak in ``opt_state``.
+    Starting it at zero gives the first step the same state pytree as
+    every later one, so the step compiles once instead of twice."""
+    if guard and "guard_streak" not in opt_state:
+        opt_state = {**opt_state, "guard_streak": jnp.zeros((), jnp.int32)}
+    return opt_state
+
+
+def main(argv=None, history: list | None = None):
+    """Run the launcher on ``argv``; returns the per-step losses.  A
+    ``history`` list, when given, receives one dict per step (loss,
+    grad_norm, step_s) for in-process callers that check more than the
+    loss."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",
@@ -121,6 +135,7 @@ def main(argv=None):
                     help="consecutive bad steps before restoring the last "
                          "committed checkpoint")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.autotune is not None or args.plan_cache_dir is not None \
             or args.fault_spec is not None or args.trace is not None \
@@ -177,6 +192,7 @@ def main(argv=None):
     if params is None:
         params = model.init(jax.random.PRNGKey(args.seed))
         opt_state = adamw.init_state(params)
+    opt_state = _with_guard_streak(opt_state, args.guard)
     n_params = model.param_count(params)
     print(f"[train] arch={cfg.name} params={n_params:,} "
           f"active={model.active_param_count(params):,}")
@@ -200,6 +216,9 @@ def main(argv=None):
             loss = float(metrics["loss"])
         losses.append(loss)
         dt = time.perf_counter() - t0
+        if history is not None:
+            history.append({"step": step, "loss": loss, "step_s": dt,
+                            "grad_norm": float(metrics["grad_norm"])})
         obs.metrics.train_step(step, metrics, step_s=dt)
         hb.beat(0)
         straggler.observe([dt])
@@ -224,6 +243,7 @@ def main(argv=None):
                 else:
                     params = model.init(jax.random.PRNGKey(args.seed))
                     opt_state = adamw.init_state(params)
+                opt_state = _with_guard_streak(opt_state, True)
                 gs.rolled_back()
         elif gs is not None:
             gs.observe(False)

@@ -83,8 +83,9 @@ def _ssd_chunked(xh, dt, a_log, B, C):
     cb = jnp.einsum("bnis,bnjs->bnij", Cr, Br)              # (B,nc,Q,Q)
     decay = cum[:, :, :, None, :] - cum[:, :, None, :, :]   # (B,nc,Qi,Qj,H)
     mask = jnp.tril(jnp.ones((q, q), bool))
-    att = jnp.where(mask[None, None, :, :, None],
-                    jnp.exp(decay), 0.0)
+    # Mask BEFORE exp: above the diagonal decay > 0 and exp overflows, and
+    # where() would send 0 * inf = NaN back through the masked branch.
+    att = jnp.exp(jnp.where(mask[None, None, :, :, None], decay, -jnp.inf))
     att = att * cb[..., None] * dt_r[:, :, None, :, :]      # (B,nc,Qi,Qj,H)
     y_intra = jnp.einsum("bnijh,bnjhp->bnihp", att.astype(xr.dtype), xr)
 

@@ -8,6 +8,7 @@ deprecation shim, and the legacy module-constant aliases
 attention.BLOCKWISE_KV_THRESHOLD, transformer.SCAN_UNROLL).
 """
 
+import os
 import warnings
 
 import pytest
@@ -62,6 +63,49 @@ def test_env_initialization_wins_over_defaults():
 ])
 def test_interpret_env_parsing_matches_historical_rule(raw, expect):
     assert GlobalConfig(env={"BPIM2COL_INTERPRET": raw}).interpret is expect
+
+
+def test_interpret_resolves_from_the_backend_on_first_read(monkeypatch):
+    """No env var: the field stays unresolved until read, then follows the
+    backend -- the CPU interprets, a TPU compiles."""
+    import jax
+    assert FIELDS["interpret"].default is None
+    c = GlobalConfig(env={})
+    assert c._values["interpret"] is None          # nothing asked at init
+    assert c.interpret is (jax.default_backend() != "tpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert GlobalConfig(env={}).interpret is False
+
+
+def test_interpreting_on_a_tpu_backend_raises(monkeypatch):
+    import jax
+    from repro.kernels import tap_gemm
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="interpret mode on a TPU"):
+        tap_gemm.resolve_interpret(True)
+    assert tap_gemm.resolve_interpret(False) is False
+
+
+@pytest.mark.parametrize("env_dir", [None, "/elsewhere/jax-cache"])
+def test_compile_cache_dir_is_fixed_or_the_env(monkeypatch, env_dir):
+    """Entry points set the checkout's fixed cache dir only when
+    JAX_COMPILATION_CACHE_DIR is unset; jax reads the variable itself."""
+    import jax
+    from repro.core import compile_cache
+    set_calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: set_calls.append((name, value)))
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    got = compile_cache.enable_compile_cache()
+    if env_dir is None:
+        assert got == compile_cache.CHECKOUT_CACHE_DIR
+        assert got.endswith(os.path.join(".cache", "jax"))
+        assert set_calls == [("jax_compilation_cache_dir", got)]
+    else:
+        assert got == env_dir and set_calls == []
 
 
 def test_repro_config_is_the_singleton():

@@ -33,7 +33,8 @@ _SCRIPT = textwrap.dedent("""
     from repro.train import train_step as TS
 
     assert len(jax.devices()) == 8, jax.devices()
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     cfg = get_smoke_config("smollm_360m")
     model = M.build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
@@ -87,7 +88,8 @@ _CONV_SCRIPT = textwrap.dedent("""
 
     policy = %(policy)r
     assert len(jax.devices()) == 8, jax.devices()
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     cfg = M.AutoencoderConfig(c_in=3, widths=(16, 32), k=3,
                               conv_policy="lax")
     params = M.init_autoencoder(jax.random.PRNGKey(0), cfg)
@@ -180,7 +182,8 @@ def test_elastic_checkpoint_restore_onto_new_sharding(tmp_path):
     tree = {"w": np.arange(64, dtype=np.float32).reshape(8, 8),
             "b": np.ones(8, np.float32)}
     CKPT.save(str(tmp_path), 5, tree)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     shardings = {"w": NamedSharding(mesh, P("data", None)),
                  "b": NamedSharding(mesh, P())}
     step, restored = CKPT.restore(str(tmp_path), shardings=shardings)

@@ -191,6 +191,22 @@ class TestRuntimeDegradation:
             with pytest.raises(RuntimeError, match="engine down"):
                 conv2d(x, w, stride=2, padding=1, policy="lax")
 
+    def test_unarmed_engine_failure_propagates(self, monkeypatch):
+        """With no fault armed, an engine that raises is surfaced, never
+        served by another engine: no degradation, no quarantine."""
+        def broken(*a, **k):
+            raise RuntimeError("kernel down")
+
+        monkeypatch.setitem(conv.ENGINES, "pallas", dataclasses.replace(
+            conv.ENGINES["pallas"], forward=broken))
+        assert not config.fault_spec
+        with pytest.raises(RuntimeError, match="kernel down"):
+            conv2d(_x(), _w(), stride=2, padding=1, policy="pallas")
+        assert not any(k.startswith("forward:bp_phase")
+                       for k in dispatch_events())
+        assert not conv.runtime_failures()
+        assert not conv.quarantined_engines()
+
     def test_reset_clears_quarantine_and_failures(self):
         config.update(fault_spec="pallas.forward.launch:raise")
         inject.set_step(0)

@@ -142,6 +142,24 @@ def test_mamba2_chunked_matches_naive_scan():
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
 
 
+def test_mamba2_chunked_grads_stay_finite():
+    """Above the diagonal of a chunk the decay exponent is positive and
+    overflows for long chunks; masking it after the exp sent 0 * inf = NaN
+    into every gradient once seq reached the 128-token chunk."""
+    from repro.models import mamba2 as M2
+    b, l, h, p, s = 1, 128, 2, 8, 4
+    r = np.random.RandomState(1)
+    xh = jnp.asarray(r.randn(b, l, h, p), jnp.float32)
+    dt = jnp.asarray(np.abs(r.randn(b, l, h)) + 1.0, jnp.float32)
+    a_log = jnp.asarray(r.randn(h) + 1.0, jnp.float32)
+    B = jnp.asarray(r.randn(b, l, s), jnp.float32)
+    C = jnp.asarray(r.randn(b, l, s), jnp.float32)
+    grads = jax.grad(lambda *a: jnp.sum(M2._ssd_chunked(*a) ** 2),
+                     argnums=(0, 1, 2, 3, 4))(xh, dt, a_log, B, C)
+    for g in grads:
+        assert np.isfinite(np.asarray(g)).all()
+
+
 def test_window_skip_attention_matches_dense():
     """Perf-iteration path: O(L*W) local-window schedule == dense attention."""
     from repro.models import attention as A

@@ -35,20 +35,36 @@ def _restore_budget():
     config.update(vmem_budget_bytes=old)
 
 
-def _budget_forcing_splits(d: ConvDims, target: int) -> int:
-    """Walk the planner's own candidate sequence down to the budget whose
-    best-fitting forward plan has exactly ``target`` spatial splits."""
-    budget = ops.forward_plan(d, 1 << 40).bytes_needed
+#: per-pass planners as (ConvDims, budget) -> TilePlan.
+PLANNERS = {
+    "forward": ops.forward_plan,
+    "weight_grad": ops.weight_grad_plan,
+    "input_grad": lambda d, b: ops.input_grad_plan(d, b).tile,
+}
+
+
+def _budget_forcing_splits(d: ConvDims, target: int,
+                           role: str = "forward") -> int:
+    """Walk one pass's own candidate sequence down to the largest budget
+    whose best-fitting plan has at least ``target`` spatial splits (exactly
+    ``target`` wherever whole-row tiles can produce that many)."""
+    plan_fn = PLANNERS[role]
+    budget = plan_fn(d, 1 << 40).bytes_needed
     for _ in range(64):
-        plan = ops.forward_plan(d, budget)
+        plan = plan_fn(d, budget)
         assert plan.fits, f"planner gave up before reaching {target} splits"
-        if plan.spatial_splits == target:
+        if plan.spatial_splits >= target:
             return budget
-        assert plan.spatial_splits < target, (
-            f"candidate sequence skipped {target} splits "
-            f"(got {plan.spatial_splits})")
         budget = plan.bytes_needed - 1
     pytest.fail(f"no budget found for {target} spatial splits")
+
+
+def _run_pass(role: str, d: ConvDims, x, w, dy):
+    if role == "forward":
+        return ops.conv2d_forward(x, w, d)
+    if role == "input_grad":
+        return ops.conv2d_input_grad(dy, w, d)
+    return ops.conv2d_weight_grad(x, dy, d)
 
 
 @pytest.mark.parametrize("target_splits", [1, 2, 4])
@@ -56,47 +72,43 @@ def test_budget_forces_spatial_splits(target_splits):
     x, w, dy = _data(D)
     want_y = conv2d_lax(x, w, D)
     want_di, want_dw = conv_grads_lax(x, w, dy, D)
-    base_y = ops.conv2d_forward(x, w, D)          # full default budget
-    base_di = ops.conv2d_input_grad(dy, w, D)
-    base_dw = ops.conv2d_weight_grad(x, dy, D)
-
-    config.update(vmem_budget_bytes=_budget_forcing_splits(D, target_splits))
-    fp = ops.forward_plan(D)
-    assert fp.fits and fp.spatial_splits == target_splits
-    assert ops.weight_grad_plan(D).fits
-    assert ops.input_grad_plan(D) is not None, (
-        "input grad must tile, not fall back, under a reduced budget")
-
-    y = ops.conv2d_forward(x, w, D)
-    di = ops.conv2d_input_grad(dy, w, D)
-    dw = ops.conv2d_weight_grad(x, dy, D)
-    # Tiled vs untiled Pallas: identical math, only the dispatch geometry
-    # changed -- agreement at (near-)bit level.
-    np.testing.assert_allclose(y, base_y, rtol=1e-4, atol=1e-5)
-    np.testing.assert_allclose(di, base_di, rtol=1e-4, atol=1e-5)
-    np.testing.assert_allclose(dw, base_dw, rtol=1e-4, atol=1e-4)
-    # And against the lax ground truth.
-    np.testing.assert_allclose(y, want_y, rtol=5e-4, atol=5e-4)
-    np.testing.assert_allclose(di, want_di, rtol=5e-4, atol=5e-4)
-    np.testing.assert_allclose(dw, want_dw, rtol=5e-3, atol=5e-3)
+    want = {"forward": want_y, "input_grad": want_di, "weight_grad": want_dw}
+    tol = {"forward": 5e-4, "input_grad": 5e-4, "weight_grad": 5e-3}
+    base_atol = {"forward": 1e-5, "input_grad": 1e-5, "weight_grad": 1e-4}
+    for role in PLANNERS:
+        base = _run_pass(role, D, x, w, dy)          # full default budget
+        budget = _budget_forcing_splits(D, target_splits, role)
+        with config.override(vmem_budget_bytes=budget):
+            plan = PLANNERS[role](D, None)
+            assert plan.fits and plan.spatial_splits == target_splits, role
+            got = _run_pass(role, D, x, w, dy)
+        # Tiled vs untiled Pallas: identical math, only the dispatch
+        # geometry changed -- agreement at (near-)bit level.
+        np.testing.assert_allclose(got, base, rtol=1e-4,
+                                   atol=base_atol[role], err_msg=role)
+        # And against the lax ground truth.
+        np.testing.assert_allclose(got, want[role], rtol=tol[role],
+                                   atol=tol[role], err_msg=role)
 
 
 def test_spatially_split_plans_stay_correct_across_strides():
-    """2x2-ish splits forced on every op at once, swept over strides."""
+    """Every op forced to >= 4 spatial tiles at once, swept over strides."""
     for s in (1, 2, 3):
         d = ConvDims(B=1, C=4, H_i=13, W_i=13, N=5, K_h=3, K_w=3, S=s,
                      P_h=1, P_w=1)
         x, w, dy = _data(d, seed=s)
         want_y = conv2d_lax(x, w, d)
         want_di, want_dw = conv_grads_lax(x, w, dy, d)
-        config.update(vmem_budget_bytes=_budget_forcing_splits(d, 4))
-        assert ops.input_grad_plan(d) is not None
-        np.testing.assert_allclose(ops.conv2d_forward(x, w, d), want_y,
-                                   rtol=5e-4, atol=5e-4, err_msg=f"S={s}")
-        np.testing.assert_allclose(ops.conv2d_input_grad(dy, w, d), want_di,
-                                   rtol=5e-4, atol=5e-4, err_msg=f"S={s}")
-        np.testing.assert_allclose(ops.conv2d_weight_grad(x, dy, d), want_dw,
-                                   rtol=5e-3, atol=5e-3, err_msg=f"S={s}")
+        want = {"forward": want_y, "input_grad": want_di,
+                "weight_grad": want_dw}
+        tol = {"forward": 5e-4, "input_grad": 5e-4, "weight_grad": 5e-3}
+        for role in PLANNERS:
+            budget = _budget_forcing_splits(d, 4, role)
+            with config.override(vmem_budget_bytes=budget):
+                assert PLANNERS[role](d, None).spatial_splits >= 4, role
+                np.testing.assert_allclose(
+                    _run_pass(role, d, x, w, dy), want[role],
+                    rtol=tol[role], atol=tol[role], err_msg=f"{role} S={s}")
 
 
 def test_large_shapes_take_pallas_path():
