@@ -1,0 +1,13 @@
+"""Share of its roofline that every conv's forward pass reaches (%).
+
+The least time of each needed conv's forward pass, on whatever engine
+it ran (``bench.flops.least_seconds``), times the steps, over the device
+time of the ops under the program's ``conv_forward`` and ``conv_forward_T``
+named scopes in the window (``bench.scopes``), glue included.
+"""
+
+from bench.scopes import pass_roofline
+
+
+def reduce(ctx):
+    return pass_roofline(ctx, "forward")

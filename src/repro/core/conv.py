@@ -659,7 +659,7 @@ def _execute(pass_name: str, requested: str, d: ConvDims, transposed: bool,
             probing = True
             _record_event(f"{pkey}:{cand}:probe")
         try:
-            with obs_trace.dispatch_span(pkey, cand, d):
+            with obs_trace.conv_pass(pkey, cand, d):
                 out = run(ENGINES[cand])
         except Exception as e:
             if not config.fault_spec:

@@ -186,6 +186,12 @@ def _annotate(plan, **kw):
     return dataclasses.replace(plan, **kw)
 
 
+def memoized(role: str, d: ConvDims, budget: int):
+    """The plan this process already resolved for one planning problem,
+    or None."""
+    return _MEMO.get(plan_key(role, d, budget))
+
+
 def tuned_plan(role: str, d: ConvDims, budget: int, analytic):
     """The tuned (or cache-served, or annotated-analytic) plan for one
     planning problem.  ``analytic`` is the already-resolved analytic plan

@@ -28,7 +28,8 @@ so there is no way to be served a stale plan.  Repeated layer shapes --
 every step of a training run retraces the same convs -- skip the search
 entirely.  ``tile_plan_cache_info()`` exposes hit counts;
 ``clear_tile_plan_cache()`` resets; ``plan_events()`` counts planned-vs-
-fallback outcomes (one event per unique shape/budget) for benchmarks & CI.
+fallback outcomes (one event per unique shape/budget) for benchmarks & CI,
+and ``plan_seconds()`` the host seconds those unique resolutions took.
 
 When ``repro.config.autotune`` is not ``"off"``, the public planners
 (:func:`forward_plan` / :func:`weight_grad_plan` / :func:`input_grad_plan`)
@@ -50,9 +51,11 @@ readable and assignable as deprecated aliases of the config fields.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import sys
+import time
 import types
 import warnings
 from typing import Callable
@@ -66,6 +69,7 @@ from repro.core import phase_decomp
 from repro.ft.inject import fault_point
 from repro.kernels import tap_gemm as tg
 from repro.obs import events as obs_events
+from repro.obs.trace import GLUE_SCOPE
 from repro.kernels.tap_gemm import LANE, SUBLANE, _cdiv, _round_up, _taps_halo
 
 #: most GEMM rows (``th * tw``) one grid step may hold.  Mosaic unrolls each
@@ -83,12 +87,36 @@ def _count_event(name: str) -> None:
     obs_events.emit("plan", name)
 
 
+#: host seconds spent resolving tile plans that no cache held.
+_PLAN_CLOCK = {"seconds": 0.0}
+
+
+@contextlib.contextmanager
+def _plan_clock():
+    """Add the host time of one plan resolution to :func:`plan_seconds`."""
+    t0 = time.perf_counter()
+    try:
+        yield
+    finally:
+        _PLAN_CLOCK["seconds"] += time.perf_counter() - t0
+
+
 def plan_events() -> dict[str, int]:
     return dict(PLAN_EVENTS)
 
 
+def plan_seconds() -> float:
+    """Host seconds spent resolving tile plans since the last
+    :func:`reset_plan_events`: the analytic search and the autotuner, on a
+    miss of their caches only (a cached plan costs nothing).  Planning
+    runs while jax traces a program, so this is set-up time, never a
+    step's."""
+    return _PLAN_CLOCK["seconds"]
+
+
 def reset_plan_events() -> None:
     PLAN_EVENTS.clear()
+    _PLAN_CLOCK["seconds"] = 0.0
     # Keep the bus-backed view in lockstep with the legacy dict (no-op off).
     obs_events.drop("plan")
 
@@ -428,14 +456,19 @@ def _autotuned(role: str, d: ConvDims, budget: int, analytic):
     if analytic is None or not getattr(analytic, "fits", True):
         return analytic
     from repro.kernels import autotune
-    return autotune.tuned_plan(role, d, budget, analytic)
+    memo = autotune.memoized(role, d, budget)
+    if memo is not None:
+        return memo
+    with _plan_clock():
+        return autotune.tuned_plan(role, d, budget, analytic)
 
 
 def _analytic_plan(role: str, d: ConvDims, budget: int) -> TilePlan:
-    g = _geom(role, d)
-    *tile, fits = _search_tiles(g.oh, g.ow, g.cost, budget)
-    _count_event(f"{role}_pallas" if fits else f"{role}_fallback")
-    return g.plan(*tile, fits=fits)
+    with _plan_clock():
+        g = _geom(role, d)
+        *tile, fits = _search_tiles(g.oh, g.ow, g.cost, budget)
+        _count_event(f"{role}_pallas" if fits else f"{role}_fallback")
+        return g.plan(*tile, fits=fits)
 
 
 def forward_plan(d: ConvDims, budget: int | None = None) -> TilePlan:
@@ -636,18 +669,21 @@ def conv2d_forward(x: jax.Array, w: jax.Array, d: ConvDims,
             rhs_dilation=(d.D_h, d.D_w),
             dimension_numbers=("NCHW", "OIHW", "NCHW"))
     fault_point("pallas.forward.launch")
-    xp = zero_pad(x, d.P_h, d.P_w, d.p_h_hi, d.p_w_hi)
-    src = _phase_split(_to_nhwc(xp), (d.s_h, d.s_w))  # (sh*sw, B, Hq, Wq, C)
-    src = _pad_to(src, plan.cin_pad)
-    wt = w.transpose(2, 3, 1, 0).reshape(d.k_taps_h * d.k_taps_w, d.C, d.N)
-    wt = _pad_to(wt, plan.cin_pad, axis=1)
-    wt = _pad_to(wt, plan.cout_pad, axis=2)
+    with jax.named_scope(GLUE_SCOPE):
+        xp = zero_pad(x, d.P_h, d.P_w, d.p_h_hi, d.p_w_hi)
+        src = _phase_split(_to_nhwc(xp), (d.s_h, d.s_w))  # (sh*sw,B,Hq,Wq,C)
+        src = _pad_to(src, plan.cin_pad)
+        wt = w.transpose(2, 3, 1, 0).reshape(d.k_taps_h * d.k_taps_w, d.C,
+                                             d.N)
+        wt = _pad_to(wt, plan.cin_pad, axis=1)
+        wt = _pad_to(wt, plan.cout_pad, axis=2)
     y = tg.tap_gemm(src, wt, plan.taps, d.H_o, d.W_o,
                     cin_tile=plan.cin_tile, cout_tile=plan.cout_tile,
                     oh_tile=plan.oh_tile, ow_tile=plan.ow_tile,
                     out_dtype=x.dtype,
                     vmem_limit_bytes=config.vmem_budget_bytes)
-    return _from_nhwc(y[..., :d.N])
+    with jax.named_scope(GLUE_SCOPE):
+        return _from_nhwc(y[..., :d.N])
 
 
 # ---------------------------------------------------------------------------
@@ -667,32 +703,34 @@ def conv2d_input_grad(dy: jax.Array, w: jax.Array, d: ConvDims,
         return phase_decomp.input_grad_phase(dy, w_eff, d)
     fault_point("pallas.input_grad.launch")
     tile = pp.tile
-    wf = rot180(w)                                 # (N, C, k_taps, k_taps)
-    blocks = []
-    for spec in pp.phase_specs:
-        if spec is None:                                 # phase gets no taps
-            blocks.append(jnp.zeros((pp.t_max, d.N, d.C), wf.dtype))
-            continue
-        rows, cols = spec
-        wk = jnp.take(jnp.take(wf, jnp.asarray(rows, jnp.int32), axis=2),
-                      jnp.asarray(cols, jnp.int32), axis=3)
-        wk = wk.transpose(2, 3, 0, 1).reshape(len(rows) * len(cols),
-                                              d.N, d.C)
-        blocks.append(_pad_to(wk, pp.t_max, axis=0))
-    wk_stack = jnp.stack(blocks)                         # (sh*sw, T, N, C)
-    wk_stack = _pad_to(wk_stack, tile.cin_pad, axis=2)
-    wk_stack = _pad_to(wk_stack, tile.cout_pad, axis=3)
-    src = jnp.pad(_to_nhwc(dy),                          # (B, Ho+lo, Wo+lo, N)
-                  ((0, 0), (pp.g_lo_h, 0), (pp.g_lo_w, 0), (0, 0)))
-    src = _pad_to(src, tile.cin_pad)
+    with jax.named_scope(GLUE_SCOPE):
+        wf = rot180(w)                             # (N, C, k_taps, k_taps)
+        blocks = []
+        for spec in pp.phase_specs:
+            if spec is None:                             # phase gets no taps
+                blocks.append(jnp.zeros((pp.t_max, d.N, d.C), wf.dtype))
+                continue
+            rows, cols = spec
+            wk = jnp.take(jnp.take(wf, jnp.asarray(rows, jnp.int32), axis=2),
+                          jnp.asarray(cols, jnp.int32), axis=3)
+            wk = wk.transpose(2, 3, 0, 1).reshape(len(rows) * len(cols),
+                                                  d.N, d.C)
+            blocks.append(_pad_to(wk, pp.t_max, axis=0))
+        wk_stack = jnp.stack(blocks)                     # (sh*sw, T, N, C)
+        wk_stack = _pad_to(wk_stack, tile.cin_pad, axis=2)
+        wk_stack = _pad_to(wk_stack, tile.cout_pad, axis=3)
+        src = jnp.pad(_to_nhwc(dy),                      # (B, Ho+lo, Wo+lo, N)
+                      ((0, 0), (pp.g_lo_h, 0), (pp.g_lo_w, 0), (0, 0)))
+        src = _pad_to(src, tile.cin_pad)
     out = tg.tap_gemm_phased(
         src, wk_stack, pp.phase_taps, pp.n_qh, pp.n_qw,
         cin_tile=tile.cin_tile, cout_tile=tile.cout_tile,
         oh_tile=tile.oh_tile, ow_tile=tile.ow_tile,
         out_dtype=dy.dtype,
         vmem_limit_bytes=config.vmem_budget_bytes)    # (sh*sw, B, qh, qw, C)
-    di = _phase_unsplit(out[..., :d.C], (d.s_h, d.s_w), d.H_i, d.W_i)
-    return _from_nhwc(di)
+    with jax.named_scope(GLUE_SCOPE):
+        di = _phase_unsplit(out[..., :d.C], (d.s_h, d.s_w), d.H_i, d.W_i)
+        return _from_nhwc(di)
 
 
 # ---------------------------------------------------------------------------
@@ -712,16 +750,18 @@ def conv2d_weight_grad(x: jax.Array, dy: jax.Array, d: ConvDims,
         dw = phase_decomp.weight_grad_phase(x, dy, d)   # effective extent
         return dw[..., ::d.D_h, ::d.D_w] if d.has_dilation else dw
     fault_point("pallas.weight_grad.launch")
-    xp = zero_pad(x, d.P_h, d.P_w, d.p_h_hi, d.p_w_hi)
-    src = _phase_split(_to_nhwc(xp), (d.s_h, d.s_w))
-    src = _pad_to(src, plan.cin_pad)
-    dyn = _pad_to(_to_nhwc(dy), plan.cout_pad)
+    with jax.named_scope(GLUE_SCOPE):
+        xp = zero_pad(x, d.P_h, d.P_w, d.p_h_hi, d.p_w_hi)
+        src = _phase_split(_to_nhwc(xp), (d.s_h, d.s_w))
+        src = _pad_to(src, plan.cin_pad)
+        dyn = _pad_to(_to_nhwc(dy), plan.cout_pad)
     dw = tg.tap_wgrad(src, dyn, plan.taps, d.H_o, d.W_o,
                       cin_tile=plan.cin_tile, cout_tile=plan.cout_tile,
                       oh_tile=plan.oh_tile, ow_tile=plan.ow_tile,
                       vmem_limit_bytes=config.vmem_budget_bytes)
-    dw = dw[:, :d.C, :d.N].reshape(d.k_taps_h, d.k_taps_w, d.C, d.N)
-    return dw.transpose(3, 2, 0, 1).astype(x.dtype)
+    with jax.named_scope(GLUE_SCOPE):
+        dw = dw[:, :d.C, :d.N].reshape(d.k_taps_h, d.k_taps_w, d.C, d.N)
+        return dw.transpose(3, 2, 0, 1).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -64,6 +64,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.config import config
+from repro.obs.trace import GLUE_SCOPE
 
 SUBLANE, LANE = 8, 128      # f32 vreg tile: (sublanes, lanes)
 _F32 = 4
@@ -154,13 +155,15 @@ def _tiling(oh: int, ow: int, oh_tile, ow_tile, halo_h: int, halo_w: int):
 
 
 def _fit_hw(x: jax.Array, h_axis: int, rows: int, cols: int) -> jax.Array:
-    """Zero-pad or crop two adjacent spatial axes to exactly (rows, cols)."""
-    for axis, n in ((h_axis, rows), (h_axis + 1, cols)):
-        x = jax.lax.slice_in_dim(x, 0, min(n, x.shape[axis]), axis=axis)
-        pads = [(0, 0)] * x.ndim
-        pads[axis] = (0, n - x.shape[axis])
-        x = jnp.pad(x, pads)
-    return x
+    """Zero-pad or crop two adjacent spatial axes to exactly (rows, cols):
+    layout glue before the launch, under the ``glue`` named scope."""
+    with jax.named_scope(GLUE_SCOPE):
+        for axis, n in ((h_axis, rows), (h_axis + 1, cols)):
+            x = jax.lax.slice_in_dim(x, 0, min(n, x.shape[axis]), axis=axis)
+            pads = [(0, 0)] * x.ndim
+            pads[axis] = (0, n - x.shape[axis])
+            x = jnp.pad(x, pads)
+        return x
 
 
 def _fetch_window(src, win, sem, lead, h0, w0, c0) -> None:

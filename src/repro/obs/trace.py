@@ -20,10 +20,19 @@ is positional and ``scripts/validate_trace.py`` can check balance.
 Because conv dispatch happens at jax TRACE time, conv spans measure
 trace/compile-side dispatch, not steady-state device time -- which is
 exactly where the degradation ladder and plan lookups live.
+
+The device side of a conv pass is its named scope instead
+(:func:`pass_scope`, entered with the span by :func:`conv_pass`): an
+always-on ``jax.named_scope`` that only writes the HLO ``op_name``
+metadata at trace time, so every device op of the pass -- and the
+layout glue inside it, under :data:`GLUE_SCOPE` -- carries the pass in a
+profiler trace.  It costs nothing on the device and is not gated on
+telemetry.
 """
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import threading
@@ -147,13 +156,37 @@ def conv_annotations(d, transposed: bool = False) -> dict:
 
 def dispatch_span(pkey: str, engine: str, d):
     """Span around one conv engine execution (``core/conv.py _execute``).
-    ``pkey`` is the dispatch pass key (``fwd``/``dgrad``/``wgrad`` with an
-    ``_T`` suffix for transposed convs)."""
+    ``pkey`` is the dispatch pass key (``forward``/``input_grad``/
+    ``weight_grad`` with an ``_T`` suffix for transposed convs).  Conv
+    dispatch runs while jax traces the program, so the span times the
+    set-up's dispatch and tile planning, never the device."""
     if _BUF is None:
         return _NULL
     args = {"pass": pkey, "engine": engine}
     args.update(conv_annotations(d, transposed=pkey.endswith("_T")))
     return _Span(f"conv:{pkey}:{engine}", args)
+
+
+#: the named scope of the layout rearrangements around a tap-GEMM kernel
+#: call (padding, phase split/unsplit, NCHW<->NHWC, weight tap gathers).
+GLUE_SCOPE = "glue"
+
+
+def pass_scope(pkey: str) -> str:
+    """The device-side named scope of one conv pass: ``conv_forward``,
+    ``conv_input_grad``, ``conv_weight_grad``, ``_T`` suffixed for a
+    transposed conv."""
+    return f"conv_{pkey}"
+
+
+@contextlib.contextmanager
+def conv_pass(pkey: str, engine: str, d):
+    """Both marks of one conv pass execution: the always-on named scope
+    :func:`pass_scope` (HLO metadata) and :func:`dispatch_span` (the host
+    span, recorded only while tracing is on)."""
+    import jax
+    with jax.named_scope(pass_scope(pkey)), dispatch_span(pkey, engine, d):
+        yield
 
 
 def dropped() -> int:

@@ -219,16 +219,50 @@ def test_stale_entry_re_tunes(bad_tile):
     assert healed["entries"][key]["tile"] == list(plan.tile_key)
 
 
-def test_budget_shrink_invalidates_persisted_plans():
+def test_budget_shrink_invalidates_persisted_plans(monkeypatch):
     """A winner tuned under a big budget must not be served under a small
-    one: plan_from_tile revalidates bytes_needed <= budget."""
+    one: plan_from_tile revalidates bytes_needed <= budget.  The timer is
+    fixed so that the candidate with the largest footprint wins; a budget
+    one byte below it then always leaves a smaller candidate that fits,
+    whichever tile a real timing would have picked."""
+    monkeypatch.setattr(autotune, "measure_plan",
+                        lambda role, d, plan, **_: 1e9 / plan.bytes_needed)
     _fresh()
     big = ops.forward_plan(D)
+    assert big.bytes_needed == max(
+        c.bytes_needed for c in ops.plan_candidates("forward", D))
     _fresh()
     with config.override(vmem_budget_bytes=big.bytes_needed - 1):
         plan = ops.forward_plan(D)
         assert plan.fits      # re-planned under the smaller budget
         assert plan.bytes_needed < big.bytes_needed
+
+
+def test_plan_seconds_count_cold_plans_only():
+    """The planner's clock rises when a plan is searched and tuned, not
+    when the memo serves it, and resets with the plan events."""
+    _fresh()
+    assert ops.plan_seconds() == 0.0
+    ops.forward_plan(D)
+    cold = ops.plan_seconds()
+    assert cold > 0
+    ops.forward_plan(D)
+    assert ops.plan_seconds() == cold
+    ops.reset_plan_events()
+    assert ops.plan_seconds() == 0.0
+
+
+def test_plan_seconds_count_analytic_cache_misses():
+    with config.override(autotune="off"):
+        _fresh()
+        ops.weight_grad_plan(D)
+        cold = ops.plan_seconds()
+        assert cold > 0
+        ops.weight_grad_plan(D)                  # the lru_cache holds it
+        assert ops.plan_seconds() == cold
+        ops.clear_tile_plan_cache()
+        ops.weight_grad_plan(D)                  # searched again
+        assert ops.plan_seconds() > cold
 
 
 # ---------------------------------------------------------------------------

@@ -89,7 +89,8 @@ def test_interpreting_on_a_tpu_backend_raises(monkeypatch):
 @pytest.mark.parametrize("env_dir", [None, "/elsewhere/jax-cache"])
 def test_compile_cache_dir_is_fixed_or_the_env(monkeypatch, env_dir):
     """Entry points set the checkout's fixed cache dir only when
-    JAX_COMPILATION_CACHE_DIR is unset; jax reads the variable itself."""
+    JAX_COMPILATION_CACHE_DIR is unset; jax reads the variable itself.
+    Either way the key covers op metadata (the named scopes)."""
     import jax
     from repro.core import compile_cache
     set_calls = []
@@ -100,12 +101,13 @@ def test_compile_cache_dir_is_fixed_or_the_env(monkeypatch, env_dir):
     else:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
     got = compile_cache.enable_compile_cache()
+    metadata = ("jax_compilation_cache_include_metadata_in_key", True)
     if env_dir is None:
         assert got == compile_cache.CHECKOUT_CACHE_DIR
         assert got.endswith(os.path.join(".cache", "jax"))
-        assert set_calls == [("jax_compilation_cache_dir", got)]
+        assert set_calls == [("jax_compilation_cache_dir", got), metadata]
     else:
-        assert got == env_dir and set_calls == []
+        assert got == env_dir and set_calls == [metadata]
 
 
 def test_repro_config_is_the_singleton():

@@ -3,7 +3,9 @@ consistency with the legacy counters, span tracing + Perfetto export,
 the metrics stream, the covering reset, and the docs gate."""
 
 import json
+import re
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -48,6 +50,27 @@ def test_disarmed_span_is_shared_null_singleton():
     assert obs.trace.span("a", k=1) is obs.trace.span("b")
     d = conv.spec_dims((1, 3, 16, 16), (8, 3, 3, 3), SPEC)
     assert obs.trace.dispatch_span("fwd", "bp_phase", d) \
+        is obs.trace.span("c")
+
+
+def test_pass_scopes_are_on_with_telemetry_off():
+    """The device-side scope of each conv pass needs no telemetry: it is
+    HLO metadata written at trace time, and the span buffer stays
+    disarmed."""
+    assert not obs.enabled() and obs.trace._BUF is None
+
+    def loss(x, w):
+        return jnp.sum(conv.conv2d(x, w, SPEC, "bp_phase") ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        _x(), _w()).compile().as_text()
+    paths = re.findall(r'op_name="([^"]*)"', text)
+    for pkey in ("forward", "input_grad", "weight_grad"):
+        scope = obs.trace.pass_scope(pkey)
+        assert any(re.search(rf"\b{scope}\b", p) for p in paths), scope
+    assert obs.trace._BUF is None
+    d = conv.spec_dims((1, 3, 16, 16), (8, 3, 3, 3), SPEC)
+    assert obs.trace.dispatch_span("forward", "bp_phase", d) \
         is obs.trace.span("c")
 
 
