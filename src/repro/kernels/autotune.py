@@ -54,7 +54,7 @@ from repro.obs import trace as obs_trace
 
 #: bump when the key layout, the entry payload or the meaning of a tile
 #: changes; older files are ignored wholesale (equivalent to a cold cache).
-CACHE_SCHEMA = 2
+CACHE_SCHEMA = 3
 
 _CACHE_FILE = "plan_cache.json"
 

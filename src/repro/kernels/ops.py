@@ -10,6 +10,10 @@ Responsibilities:
     outright -- only the ``k_taps_h * k_taps_w`` real taps are ever
     enumerated, multiplied or planned for, never the ``K_h * K_w``
     zero-dilated extent.  Callers pass the COMPACT (undilated) kernel;
+  * lane packing of narrow contractions (:class:`LanePack`): a forward or
+    weight-grad pass over ``C <= 64`` channels packs shifted phase planes
+    side by side into one 128-lane tile, so the kernel runs a shorter tap
+    table instead of one dot per tap over mostly zero lanes;
   * tile-plan SEARCH under an explicit VMEM budget: the planners walk
     (spatial tile, cin tile, cout tile) candidates -- full plane first, then
     halving the larger spatial dim, then halving channel tiles -- and take
@@ -54,6 +58,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import math
 import sys
 import time
 import types
@@ -192,6 +197,60 @@ def _phase_unsplit(planes: jax.Array, s: tuple[int, int],
     return x.reshape(b, hq * s_h, wq * s_w, c)[:, :h, :w, :]
 
 
+def _pack_source(xp: jax.Array, s: tuple[int, int],
+                 pack: LanePack) -> jax.Array:
+    """(B, C, Hp, Wp) padded input -> (len(pack.planes), B, Hq, Wq,
+    width*C) lane-packed planes, zeros past each plane's end.
+
+    W stays the last dim, and the shifts are stacked on a leading axis,
+    until one transpose into the packed layout.  Built as NHWC pieces and
+    concatenated along the lanes, XLA laid out each C-lane piece padded
+    to 128 lanes in HBM: 7 GiB of temporaries for the stem's 32 pieces,
+    in a compile for a TPU v5e."""
+    s_h, s_w = s
+    bsz, c, hp, wp = xp.shape
+    hq, wq = _cdiv(hp, s_h), _cdiv(wp, s_w)
+    a_hi = max(a for a, _ in pack.shifts)
+    b_hi = max(b for _, b in pack.shifts)
+    rows, cols = hq + a_hi, wq + b_hi
+    xp = jnp.pad(xp, ((0, 0), (0, 0), (0, rows * s_h - hp),
+                      (0, cols * s_w - wp)))
+    planes = xp.reshape(bsz, c, rows, s_h, cols, s_w).transpose(
+        0, 2, 3, 5, 1, 4).reshape(bsz, rows, s_h * s_w, c, cols)
+    out = []
+    for group in pack.planes:
+        dense = jnp.stack([planes[:, :, p] for p in group], axis=2)
+        dense = dense.reshape(bsz, rows, len(group) * c, cols)
+        block = jnp.stack([dense[:, a:a + hq, :, b:b + wq]
+                           for a, b in pack.shifts])
+        block = block.transpose(1, 2, 4, 0, 3).reshape(
+            bsz, hq, wq, len(pack.shifts) * len(group) * c)
+        out.append(_pad_to(block, pack.width * c))
+    return jnp.stack(out)
+
+
+def _pack_weights(wt: jax.Array, pack: LanePack) -> jax.Array:
+    """(T, C, N) real-tap weights -> (len(pack.taps), width*C, N): real
+    tap k fills slot ``where[k][1]`` of packed tap ``where[k][0]``; every
+    other (packed tap, slot) pair gets zero rows."""
+    t, c, n = wt.shape
+    idx = [[t] * pack.width for _ in pack.taps]       # t: the zero block
+    for k, (pt, j) in enumerate(pack.where):
+        idx[pt][j] = k
+    wz = jnp.concatenate([wt, jnp.zeros((1, c, n), wt.dtype)])
+    return wz[jnp.asarray(idx, jnp.int32)].reshape(len(pack.taps),
+                                                   pack.width * c, n)
+
+
+def _unpack_wgrad(dw: jax.Array, pack: LanePack) -> jax.Array:
+    """(len(pack.taps), >= width*C, N) packed weight grad -> (T, C, N) of
+    the real taps; the pairs no real tap maps to are dropped."""
+    n = dw.shape[-1]
+    dw = dw[:, :pack.width * pack.c].reshape(-1, pack.c, n)
+    rows = [pt * pack.width + j for pt, j in pack.where]
+    return dw[jnp.asarray(rows, jnp.int32)]
+
+
 # ---------------------------------------------------------------------------
 # Tile search: (spatial tile, cin tile, cout tile) under the VMEM budget
 # ---------------------------------------------------------------------------
@@ -246,8 +305,96 @@ def _search_tiles_topk(oh, ow, cost_fn, budget, k):
 # ---------------------------------------------------------------------------
 
 @dataclasses.dataclass(frozen=True)
+class LanePack:
+    """Lane packing of a narrow contraction: several taps share one
+    128-lane contraction tile instead of each padding ``c`` channels to
+    128 on its own.
+
+    Packed plane ``i`` holds the phase planes ``planes[i]`` side by side
+    in its lanes, repeated once per shift ``(a, b)`` of ``shifts``: slot
+    ``j = k * len(planes[i]) + m`` is shift ``shifts[k]`` of phase plane
+    ``planes[i][m]`` and fills lanes ``[j*c, (j+1)*c)``, so
+    ``packed[i, b_, h, w, j*c + ch] = src[plane, b_, h + a, w + b, ch]``.
+    ``taps`` is the kernel's tap table over the packed planes, and
+    ``where[k] = (t, j)`` places real tap ``k``: packed tap ``t``, slot
+    ``j``.  A (packed tap, slot) pair that no real tap maps to gets zero
+    forward weights, and its weight-grad rows are dropped."""
+    c: int
+    planes: tuple[tuple[int, ...], ...]
+    shifts: tuple[tuple[int, int], ...]
+    taps: tuple[tuple[int, int, int], ...]
+    where: tuple[tuple[int, int], ...]
+
+    @property
+    def slots(self) -> tuple[tuple[tuple[int, int, int], ...], ...]:
+        """Per packed plane, ``(plane, a, b)`` of each slot in lane order."""
+        return tuple(tuple((p, a, b) for a, b in self.shifts for p in group)
+                     for group in self.planes)
+
+    @property
+    def width(self) -> int:
+        """Slots of the widest packed plane."""
+        return len(self.shifts) * max(len(g) for g in self.planes)
+
+    @property
+    def lane_fill(self) -> float:
+        return self.width * self.c / LANE
+
+
+def _shifts(span: int, most: int) -> int:
+    """Fewest shifts, at most ``most``, that cut ``span`` offsets to as
+    few packed offsets as ``most`` shifts would."""
+    return _cdiv(span, _cdiv(span, min(span, most)))
+
+
+def _lane_pack(taps, c: int) -> LanePack | None:
+    """Pack the taps of a ``c``-channel contraction into 128 lanes, or
+    None when ``c > 64`` or packing would not shorten the tap table.
+
+    Slots are taken in this order while they fit ``g = 128 // c``: phase
+    planes first (disjoint data: no byte is added), then W shifts (with
+    every W offset covered, each packed slice is sublane-aligned,
+    ``dv' = 0``), then H shifts.  A shift count is cut to the fewest that
+    give the same packed offsets.  Offsets step by the gcd of the real
+    ones, so a dilated table's gaps take no slot.  The packed source
+    never outgrows the unpacked one: a packed plane holds at least one
+    phase plane's worth of lanes in place of a whole 128-lane plane, and
+    a shift only shortens the halo."""
+    g = LANE // c
+    if g < 2:
+        return None
+    planes = sorted({p for p, _, _ in taps})
+    step_h = math.gcd(*(du for _, du, _ in taps)) or 1
+    step_w = math.gcd(*(dv for _, _, dv in taps)) or 1
+    k = min(len(planes), g)
+    nb = _shifts(1 + max(dv for *_, dv in taps) // step_w, g // k)
+    na = _shifts(1 + max(du for _, du, _ in taps) // step_h, g // (k * nb))
+    groups = tuple(tuple(planes[i:i + k]) for i in range(0, len(planes), k))
+    shifts = tuple((a * step_h, b * step_w)
+                   for a in range(na) for b in range(nb))
+    packed: dict[tuple[int, int, int], int] = {}
+    where = []
+    for p, du, dv in taps:
+        i = planes.index(p) // k
+        a = du // step_h % na
+        b = dv // step_w % nb
+        t = packed.setdefault(
+            (i, du - a * step_h, dv - b * step_w), len(packed))
+        where.append((t, (a * nb + b) * len(groups[i])
+                      + groups[i].index(p)))
+    if len(packed) >= len(taps):
+        return None
+    return LanePack(c, groups, shifts, tuple(packed), tuple(where))
+
+
+@dataclasses.dataclass(frozen=True)
 class TilePlan:
     """One Pallas dispatch: channel + spatial tiling, tap table, footprint.
+
+    ``taps`` is always the REAL tap table.  ``pack`` is set when the
+    contraction is lane-packed (:class:`LanePack`); the kernel then runs
+    ``pack.taps`` over the packed source, and ``halo_*`` and
+    ``bytes_needed`` describe that packed window.
 
     The trailing autotune fields are metadata only (they do not change the
     dispatch): ``autotuned`` marks a MEASURED winner from
@@ -271,6 +418,7 @@ class TilePlan:
     halo_h: int
     halo_w: int
     bytes_needed: int
+    pack: LanePack | None = None
     autotuned: bool = False
     measured_us: float = -1.0
     candidates_timed: int = 0
@@ -279,6 +427,11 @@ class TilePlan:
     @property
     def spatial_splits(self) -> int:
         return self.n_th * self.n_tw
+
+    @property
+    def kernel_taps(self) -> tuple[tuple[int, int, int], ...]:
+        """The tap table the kernel runs: packed when ``pack`` is set."""
+        return self.taps if self.pack is None else self.pack.taps
 
     @property
     def tile_key(self) -> tuple[int, int, int, int]:
@@ -340,11 +493,12 @@ class _Geom:
     halo_w: int
     cost: Callable[[int, int], int]       # (th, tw) -> VMEM bytes
     phase: tuple | None = None            # _input_grad_geom, input_grad only
+    pack: LanePack | None = None          # forward / weight_grad only
 
     def plan(self, th, tw, n_th, n_tw, bytes_needed, fits=True) -> TilePlan:
         return TilePlan(fits, self.cin_pad, self.cin_tile, self.cout_pad,
                         self.cout_tile, self.taps, th, tw, n_th, n_tw,
-                        self.halo_h, self.halo_w, bytes_needed)
+                        self.halo_h, self.halo_w, bytes_needed, self.pack)
 
 
 def _geom(role: str, d: ConvDims) -> _Geom:
@@ -353,16 +507,19 @@ def _geom(role: str, d: ConvDims) -> _Geom:
     into C across all stride phases."""
     if role in ("forward", "weight_grad"):
         taps = _forward_taps(d)
-        halo_h, halo_w = _taps_halo(taps)
+        pack = _lane_pack(taps, d.C)
+        planes, kernel_taps = ((d.s_h * d.s_w, taps) if pack is None
+                               else (len(pack.planes), pack.taps))
+        halo_h, halo_w = _taps_halo(kernel_taps)
         cin_p, cit = _channel_tile(d.C, contraction=True)
         cout_p, cot = _channel_tile(d.N, contraction=False)
         vmem = (tg.tap_gemm_vmem if role == "forward"
                 else tg.tap_wgrad_vmem)
-        planes = d.s_h * d.s_w
         return _Geom(d.H_o, d.W_o, cin_p, cit, cout_p, cot, taps,
                      halo_h, halo_w,
-                     lambda th, tw: vmem(planes, len(taps), th, tw, halo_h,
-                                         halo_w, cit, cot))
+                     lambda th, tw: vmem(planes, len(kernel_taps), th, tw,
+                                         halo_h, halo_w, cit, cot),
+                     pack=pack)
     if role == "input_grad":
         phase = _input_grad_geom(d)
         n_qh, n_qw, _, _, t_max, _, _, halo_h, halo_w = phase
@@ -468,6 +625,8 @@ def _analytic_plan(role: str, d: ConvDims, budget: int) -> TilePlan:
         g = _geom(role, d)
         *tile, fits = _search_tiles(g.oh, g.ow, g.cost, budget)
         _count_event(f"{role}_pallas" if fits else f"{role}_fallback")
+        if fits and g.pack is not None:
+            _count_event(f"{role}_packed")
         return g.plan(*tile, fits=fits)
 
 
@@ -625,6 +784,10 @@ def plan_report(d: ConvDims, budget: int | None = None) -> dict[str, object]:
              "halo": [p.halo_h, p.halo_w],
              "taps": len(p.taps),
              "bytes_needed": p.bytes_needed}
+        if p.pack is not None:
+            t["pack"] = {"slots": sum(len(s) for s in p.pack.slots),
+                         "packed_taps": len(p.pack.taps),
+                         "lane_fill": p.pack.lane_fill}
         if p.cache:        # the plan went through the autotuner
             t["autotune"] = {"autotuned": p.autotuned,
                              "measured_us": p.measured_us,
@@ -671,13 +834,17 @@ def conv2d_forward(x: jax.Array, w: jax.Array, d: ConvDims,
     fault_point("pallas.forward.launch")
     with jax.named_scope(GLUE_SCOPE):
         xp = zero_pad(x, d.P_h, d.P_w, d.p_h_hi, d.p_w_hi)
-        src = _phase_split(_to_nhwc(xp), (d.s_h, d.s_w))  # (sh*sw,B,Hq,Wq,C)
-        src = _pad_to(src, plan.cin_pad)
         wt = w.transpose(2, 3, 1, 0).reshape(d.k_taps_h * d.k_taps_w, d.C,
                                              d.N)
+        if plan.pack is None:                         # (sh*sw,B,Hq,Wq,C)
+            src = _phase_split(_to_nhwc(xp), (d.s_h, d.s_w))
+        else:
+            src = _pack_source(xp, (d.s_h, d.s_w), plan.pack)
+            wt = _pack_weights(wt, plan.pack)
+        src = _pad_to(src, plan.cin_pad)
         wt = _pad_to(wt, plan.cin_pad, axis=1)
         wt = _pad_to(wt, plan.cout_pad, axis=2)
-    y = tg.tap_gemm(src, wt, plan.taps, d.H_o, d.W_o,
+    y = tg.tap_gemm(src, wt, plan.kernel_taps, d.H_o, d.W_o,
                     cin_tile=plan.cin_tile, cout_tile=plan.cout_tile,
                     oh_tile=plan.oh_tile, ow_tile=plan.ow_tile,
                     out_dtype=x.dtype,
@@ -752,15 +919,20 @@ def conv2d_weight_grad(x: jax.Array, dy: jax.Array, d: ConvDims,
     fault_point("pallas.weight_grad.launch")
     with jax.named_scope(GLUE_SCOPE):
         xp = zero_pad(x, d.P_h, d.P_w, d.p_h_hi, d.p_w_hi)
-        src = _phase_split(_to_nhwc(xp), (d.s_h, d.s_w))
+        src = (_phase_split(_to_nhwc(xp), (d.s_h, d.s_w))
+               if plan.pack is None
+               else _pack_source(xp, (d.s_h, d.s_w), plan.pack))
         src = _pad_to(src, plan.cin_pad)
         dyn = _pad_to(_to_nhwc(dy), plan.cout_pad)
-    dw = tg.tap_wgrad(src, dyn, plan.taps, d.H_o, d.W_o,
+    dw = tg.tap_wgrad(src, dyn, plan.kernel_taps, d.H_o, d.W_o,
                       cin_tile=plan.cin_tile, cout_tile=plan.cout_tile,
                       oh_tile=plan.oh_tile, ow_tile=plan.ow_tile,
                       vmem_limit_bytes=config.vmem_budget_bytes)
     with jax.named_scope(GLUE_SCOPE):
-        dw = dw[:, :d.C, :d.N].reshape(d.k_taps_h, d.k_taps_w, d.C, d.N)
+        dw = dw[..., :d.N]
+        dw = (dw[:, :d.C] if plan.pack is None
+              else _unpack_wgrad(dw, plan.pack))
+        dw = dw.reshape(d.k_taps_h, d.k_taps_w, d.C, d.N)
         return dw.transpose(3, 2, 0, 1).astype(x.dtype)
 
 
