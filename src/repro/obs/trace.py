@@ -170,6 +170,12 @@ def dispatch_span(pkey: str, engine: str, d):
 #: the named scope of the layout rearrangements around a tap-GEMM kernel
 #: call (padding, phase split/unsplit, NCHW<->NHWC, weight tap gathers).
 GLUE_SCOPE = "glue"
+#: the named scopes of a whole network's work outside its convs
+#: (``models/resnet.py``): each batch norm with the residual add and ReLU
+#: that follow it, the max pool, and the head (global pool, fc, loss).
+NORM_SCOPE = "batch_norm"
+POOL_SCOPE = "max_pool"
+HEAD_SCOPE = "head"
 
 
 def pass_scope(pkey: str) -> str:
