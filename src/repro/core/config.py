@@ -135,7 +135,7 @@ FIELDS: dict[str, _Field] = {
                                 int, _check_positive_int("vmem_budget_bytes"),
                                 plan_affecting=True),
     # Measured autotuning of the tap-GEMM tile plans (kernels/autotune.py):
-    #   off     -- analytic first-fit search only (the historical behavior);
+    #   off     -- analytic tile search only (the historical behavior);
     #   measure -- time the top-k analytic candidates on device, persist the
     #              winner in the plan cache, reuse persisted winners;
     #   cached  -- never time: use persisted winners when present, analytic

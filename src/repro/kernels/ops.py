@@ -15,13 +15,14 @@ Responsibilities:
     side by side into one 128-lane tile, so the kernel runs a shorter tap
     table instead of one dot per tap over mostly zero lanes;
   * tile-plan SEARCH under an explicit VMEM budget: the planners walk
-    (spatial tile, cin tile, cout tile) candidates -- full plane first, then
-    halving the larger spatial dim, then halving channel tiles -- and take
-    the first configuration whose per-grid-step VMEM footprint fits.  A
-    shape only falls back to the jnp phase decomposition when even the
-    minimal 1x1-spatial / smallest-channel tiling exceeds the budget
-    (genuinely degenerate geometry or an absurdly small budget), never
-    merely because the full spatial plane is large.
+    every (spatial tile, cin tile, cout tile) candidate -- spatial tiles
+    from the full plane down, channel tiles from the whole padded dim down
+    to 128 lanes -- and take the one whose per-grid-step VMEM footprint
+    fits with the fewest grid steps (:func:`_search_tiles`).  A shape only
+    falls back to the jnp phase decomposition when even the minimal
+    1x1-spatial / 128-lane tiling exceeds the budget (genuinely degenerate
+    geometry or an absurdly small budget), never merely because the full
+    spatial plane is large.
 
 Tap tables and tile choices depend only on the static ``ConvDims`` and the
 budget, so they are memoized (``functools.lru_cache``) with the budget as an
@@ -38,8 +39,8 @@ and ``plan_seconds()`` the host seconds those unique resolutions took.
 When ``repro.config.autotune`` is not ``"off"``, the public planners
 (:func:`forward_plan` / :func:`weight_grad_plan` / :func:`input_grad_plan`)
 route through ``repro.kernels.autotune``: the analytic search keeps its
-role (first-fit feasibility + fallback/event accounting), but the tile
-actually dispatched may be a MEASURED winner -- the top-k analytic
+role (feasibility + fallback/event accounting), but the tile actually
+dispatched may be a MEASURED winner -- the top-k analytic
 candidates timed on device, persisted in an on-disk plan cache.  The
 ``"auto"`` engine resolver and every ``conv2d`` dispatch consult tuned
 plans exactly as they consult analytic ones, because they all go through
@@ -162,15 +163,21 @@ def _pad_to(x, n: int, axis: int = -1):
     return jnp.pad(x, pads)
 
 
-def _channel_tile(c: int, contraction: bool) -> tuple[int, int]:
-    """(padded_c, tile) of one channel dim.  A contraction dim is the lane
-    dim of the DMA'd source window, which Mosaic copies in whole 128-lane
-    tiles, so it is padded to a multiple of 128 (zero lanes add nothing to
-    the GEMM); an output dim up to 128 stays whole, wider ones are padded
-    and tiled by 128."""
+def _channel_tiles(c: int, contraction: bool) -> tuple[int, tuple[int, ...]]:
+    """(padded_c, tiles) of one channel dim, the widest tile first.
+
+    A contraction dim is the lane dim of the DMA'd source window, which
+    Mosaic copies in whole 128-lane tiles, so it is padded to a multiple of
+    128 (zero lanes add nothing to the GEMM); an output dim up to 128 stays
+    whole, a wider one is padded to a multiple of 128.  A tile is any
+    multiple of 128 that divides the padded dim, so a window's channel
+    offset stays lane-aligned and every weight and output block is whole.
+    The planner picks one together with the spatial tile
+    (:func:`_search_tiles`)."""
     if c <= LANE and not contraction:
-        return c, c
-    return _round_up(c, LANE), LANE
+        return c, (c,)
+    c_pad = _round_up(c, LANE)
+    return c_pad, tuple(t for t in range(c_pad, 0, -LANE) if c_pad % t == 0)
 
 
 def _phase_split(xp: jax.Array, s: tuple[int, int]) -> jax.Array:
@@ -272,32 +279,36 @@ def _spatial_candidates(oh: int, ow: int):
             return
 
 
-def _search_tiles(oh, ow, cost_fn, budget):
-    """First spatial tile whose footprint fits, largest first.
-    Returns (th, tw, n_th, n_tw, bytes, fits)."""
-    last = None
-    for th, tw in _spatial_candidates(oh, ow):
-        bytes_needed = cost_fn(th, tw)
-        last = (th, tw, _cdiv(oh, th), _cdiv(ow, tw), bytes_needed)
-        if bytes_needed <= budget:
-            return (*last, True)
-    return (*last, False)
+def _fitting_tiles(g: _Geom, budget: int):
+    """Every candidate ``(th, tw, cit, cot, bytes)`` whose footprint fits,
+    best first: the fewest grid steps, then the fewest operand bytes
+    streamed, then one contraction step (the window is then fetched once
+    per spatial tile), then the larger spatial tile.  Along one channel
+    pair the spatial candidates only add grid steps, so the ranking of a
+    layer with one channel pair is the spatial search order."""
+    ranked = []
+    for cit in g.cin_tiles:
+        for cot in g.cout_tiles:
+            for i, (th, tw) in enumerate(_spatial_candidates(g.oh, g.ow)):
+                bytes_needed = g.cost(th, tw, cit, cot)
+                if bytes_needed <= budget:
+                    key = (*g.rank(th, tw, cit, cot), g.cin_pad // cit,
+                           -th * tw, i)
+                    ranked.append((key, (th, tw, cit, cot, bytes_needed)))
+    ranked.sort()
+    return [tile for _, tile in ranked]
 
 
-def _search_tiles_topk(oh, ow, cost_fn, budget, k):
-    """Up to ``k`` FITTING tiles in search order (the first is exactly
-    what :func:`_search_tiles` returns when it fits): the autotuner's
-    shortlist -- the analytically largest tile plus the next finer ones,
-    the region where the footprint model most often mispredicts real
-    hardware."""
-    out = []
-    for th, tw in _spatial_candidates(oh, ow):
-        bytes_needed = cost_fn(th, tw)
-        if bytes_needed <= budget:
-            out.append((th, tw, _cdiv(oh, th), _cdiv(ow, tw), bytes_needed))
-            if len(out) >= k:
-                break
-    return out
+def _search_tiles(g: _Geom, budget: int):
+    """The best fitting ``(th, tw, cit, cot, bytes, fits)``; when nothing
+    fits, the smallest candidate (1-row tile, narrowest channel tiles) with
+    ``fits=False``."""
+    fitting = _fitting_tiles(g, budget)
+    if fitting:
+        return (*fitting[0], True)
+    cit, cot = g.cin_tiles[-1], g.cout_tiles[-1]
+    *_, (th, tw) = _spatial_candidates(g.oh, g.ow)
+    return th, tw, cit, cot, g.cost(th, tw, cit, cot), False
 
 
 # ---------------------------------------------------------------------------
@@ -398,7 +409,7 @@ class TilePlan:
 
     The trailing autotune fields are metadata only (they do not change the
     dispatch): ``autotuned`` marks a MEASURED winner from
-    ``repro.kernels.autotune`` rather than the analytic first-fit,
+    ``repro.kernels.autotune`` rather than the analytic search,
     ``measured_us`` its best-of-reps wall-clock, ``candidates_timed`` how
     many analytic candidates were raced, and ``cache`` whether the winner
     came from the persistent plan cache (``"hit"``), was tuned fresh
@@ -479,26 +490,50 @@ def _budget_or_default(budget: int | None) -> int:
 
 @dataclasses.dataclass(frozen=True)
 class _Geom:
-    """Everything one planning problem fixes before a spatial tile is
-    chosen: the output plane the tiles cover, the channel padding and
-    tiles, the tap table and halo, and the VMEM footprint of a tile."""
+    """Everything one planning problem fixes before a tile is chosen: the
+    output plane the tiles cover, the channel padding and the channel
+    tiles to choose from, the tap table and halo, and per candidate tile
+    its VMEM footprint and its grid cost."""
     oh: int
     ow: int
     cin_pad: int
-    cin_tile: int
+    cin_tiles: tuple[int, ...]
     cout_pad: int
-    cout_tile: int
+    cout_tiles: tuple[int, ...]
     taps: tuple
     halo_h: int
     halo_w: int
-    cost: Callable[[int, int], int]       # (th, tw) -> VMEM bytes
+    cost: Callable[[int, int, int, int], int]   # (th, tw, cit, cot) -> bytes
+    rank: Callable[[int, int, int, int], tuple[int, int]]   # _grid_rank
     phase: tuple | None = None            # _input_grad_geom, input_grad only
     pack: LanePack | None = None          # forward / weight_grad only
 
-    def plan(self, th, tw, n_th, n_tw, bytes_needed, fits=True) -> TilePlan:
-        return TilePlan(fits, self.cin_pad, self.cin_tile, self.cout_pad,
-                        self.cout_tile, self.taps, th, tw, n_th, n_tw,
-                        self.halo_h, self.halo_w, bytes_needed, self.pack)
+    def plan(self, th, tw, cit, cot, bytes_needed, fits=True) -> TilePlan:
+        return TilePlan(fits, self.cin_pad, cit, self.cout_pad, cot,
+                        self.taps, th, tw, _cdiv(self.oh, th),
+                        _cdiv(self.ow, tw), self.halo_h, self.halo_w,
+                        bytes_needed, self.pack)
+
+
+def _grid_rank(b: int, oh: int, ow: int, cin_pad: int, cout_pad: int,
+               phases: int, weight_bytes: int | None):
+    """``(th, tw, cit, cot) -> (grid steps, operand bytes streamed)`` of
+    one pass: ``b * n_th * n_tw`` tiles, each in ``phases * cout_steps *
+    cin_steps`` steps.  A tap GEMM streams one weight block a step, so
+    all ``weight_bytes`` once per tile, or once in all when the grid holds
+    a single block; the weight grad (``weight_bytes=None``) streams one dY
+    block a step, so dY once per contraction step."""
+    def rank(th, tw, cit, cot):
+        tiles = b * _cdiv(oh, th) * _cdiv(ow, tw)
+        cout_steps = cout_pad // cot
+        blocks = phases * (cin_pad // cit) * cout_steps
+        if weight_bytes is not None:
+            streamed = weight_bytes * (tiles if blocks > 1 else 1)
+        else:
+            streamed = tiles * th * tw * cout_pad * tg._F32 * (
+                cin_pad // cit if tiles * cout_steps > 1 else 1)
+        return tiles * blocks, streamed
+    return rank
 
 
 def _geom(role: str, d: ConvDims) -> _Geom:
@@ -511,23 +546,32 @@ def _geom(role: str, d: ConvDims) -> _Geom:
         planes, kernel_taps = ((d.s_h * d.s_w, taps) if pack is None
                                else (len(pack.planes), pack.taps))
         halo_h, halo_w = _taps_halo(kernel_taps)
-        cin_p, cit = _channel_tile(d.C, contraction=True)
-        cout_p, cot = _channel_tile(d.N, contraction=False)
+        cin_p, cits = _channel_tiles(d.C, contraction=True)
+        cout_p, cots = _channel_tiles(d.N, contraction=False)
         vmem = (tg.tap_gemm_vmem if role == "forward"
                 else tg.tap_wgrad_vmem)
-        return _Geom(d.H_o, d.W_o, cin_p, cit, cout_p, cot, taps,
+        t = len(kernel_taps)
+        weights = (t * cin_p * cout_p * tg._F32 if role == "forward"
+                   else None)
+        return _Geom(d.H_o, d.W_o, cin_p, cits, cout_p, cots, taps,
                      halo_h, halo_w,
-                     lambda th, tw: vmem(planes, len(kernel_taps), th, tw,
-                                         halo_h, halo_w, cit, cot),
+                     lambda th, tw, cit, cot: vmem(planes, t, th, tw, halo_h,
+                                                   halo_w, cit, cot),
+                     _grid_rank(d.B, d.H_o, d.W_o, cin_p, cout_p, 1,
+                                weights),
                      pack=pack)
     if role == "input_grad":
         phase = _input_grad_geom(d)
         n_qh, n_qw, _, _, t_max, _, _, halo_h, halo_w = phase
-        cin_p, cit = _channel_tile(d.N, contraction=True)
-        cout_p, cot = _channel_tile(d.C, contraction=False)
-        return _Geom(n_qh, n_qw, cin_p, cit, cout_p, cot, (), halo_h, halo_w,
-                     lambda th, tw: tg.tap_gemm_vmem(
+        cin_p, cits = _channel_tiles(d.N, contraction=True)
+        cout_p, cots = _channel_tiles(d.C, contraction=False)
+        return _Geom(n_qh, n_qw, cin_p, cits, cout_p, cots, (), halo_h,
+                     halo_w,
+                     lambda th, tw, cit, cot: tg.tap_gemm_vmem(
                          1, t_max, th, tw, halo_h, halo_w, cit, cot),
+                     _grid_rank(d.B, n_qh, n_qw, cin_p, cout_p,
+                                d.s_h * d.s_w, d.s_h * d.s_w * t_max
+                                * cin_p * cout_p * tg._F32),
                      phase)
     raise ValueError(f"unknown plan role {role!r}; roles: {PLAN_ROLES}")
 
@@ -623,11 +667,14 @@ def _autotuned(role: str, d: ConvDims, budget: int, analytic):
 def _analytic_plan(role: str, d: ConvDims, budget: int) -> TilePlan:
     with _plan_clock():
         g = _geom(role, d)
-        *tile, fits = _search_tiles(g.oh, g.ow, g.cost, budget)
+        *tile, fits = _search_tiles(g, budget)
+        plan = g.plan(*tile, fits=fits)
         _count_event(f"{role}_pallas" if fits else f"{role}_fallback")
         if fits and g.pack is not None:
             _count_event(f"{role}_packed")
-        return g.plan(*tile, fits=fits)
+        if fits and max(plan.cin_tile, plan.cout_tile) > LANE:
+            _count_event(f"{role}_wide")
+        return plan
 
 
 def forward_plan(d: ConvDims, budget: int | None = None) -> TilePlan:
@@ -713,15 +760,15 @@ def shard_halo(d: ConvDims) -> tuple[tuple[int, int], tuple[int, int]]:
 
 def plan_candidates(role: str, d: ConvDims, budget: int | None = None,
                     k: int | None = None):
-    """The autotuner's shortlist: up to ``k`` analytically FITTING plans in
-    search order (first element == the analytic winner).  ``input_grad``
-    candidates are full :class:`PhasePlan` objects sharing one geometry.
-    Pure and unmemoized; records no plan events."""
+    """The autotuner's shortlist: up to ``k`` analytically FITTING plans,
+    best ranked first (first element == the analytic winner), across
+    spatial and channel tiles alike.  ``input_grad`` candidates are full
+    :class:`PhasePlan` objects sharing one geometry.  Pure and unmemoized;
+    records no plan events."""
     d, budget = _canonical(d), _budget_or_default(budget)
     k = config.autotune_top_k if k is None else k
     g = _geom(role, d)
-    plans = [g.plan(*t)
-             for t in _search_tiles_topk(g.oh, g.ow, g.cost, budget, k)]
+    plans = [g.plan(*t) for t in _fitting_tiles(g, budget)[:k]]
     if role == "input_grad":
         return [_phase_plan_of(d, g.phase, t) for t in plans]
     return plans
@@ -743,12 +790,12 @@ def plan_from_tile(role: str, d: ConvDims, budget: int | None,
         return None
     if (th, tw) not in set(_spatial_candidates(g.oh, g.ow)):
         return None
-    if (cit, cot) != (g.cin_tile, g.cout_tile):
+    if cit not in g.cin_tiles or cot not in g.cout_tiles:
         return None
-    bytes_needed = g.cost(th, tw)
+    bytes_needed = g.cost(th, tw, cit, cot)
     if bytes_needed > budget:
         return None
-    plan = g.plan(th, tw, _cdiv(g.oh, th), _cdiv(g.ow, tw), bytes_needed)
+    plan = g.plan(th, tw, cit, cot, bytes_needed)
     if role == "input_grad":
         return _phase_plan_of(d, g.phase, plan)
     return plan

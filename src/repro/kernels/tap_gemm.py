@@ -49,7 +49,10 @@ All shapes entering ``pl.pallas_call`` are static; tile sizes are chosen by
 ``ops.py`` under an explicit VMEM budget, which the builders hand to Mosaic
 as ``vmem_limit_bytes``.  The ``*_vmem`` functions below are the footprint
 model both sides share: every buffer padded to (8, 128) tiles, pipelined
-blocks counted twice (double buffering), plus one tap's GEMM operands.
+blocks counted twice (double buffering), plus one tap's GEMM temporaries,
+which a wide f32 dot at HIGHEST makes several times its operand slice.
+The model assumes every operand in HBM: where XLA places a weight or
+output block in VMEM instead, Mosaic needs less scoped VMEM, never more.
 """
 
 from __future__ import annotations
@@ -116,15 +119,32 @@ def window_w(tw: int, halo_w: int) -> int:
     return _round_up(tw + halo_w, SUBLANE)
 
 
+#: scoped VMEM Mosaic takes beyond the buffers and dot temporaries the
+#: model names: internal scratch and the relayout copies of tap slices at
+#: sublane offsets.  The model fell short by up to 0.61 MiB without it, in
+#: compiles of every candidate tile of ResNet-50's strided convs for a v5e.
+_MOSAIC_SLACK = 768 * 1024
+
+
 def _gemm_temps(th: int, tw: int, cit: int, cot: int) -> int:
     """One tap's live GEMM values: the (th*tw, cit) operand slice and the
-    (th*tw, cot) product."""
-    return vmem_bytes((th * tw, cit)) + vmem_bytes((th * tw, cot))
+    (th*tw, cot) product.  An f32 dot at ``Precision.HIGHEST`` wider than
+    128 output lanes also keeps the operand slice's bf16 splits on
+    Mosaic's stack: up to four f32 copies of the slice plus two 128-lane
+    columns, measured by compiling single kernels for a v5e with the
+    operands in HBM at 224 and 896 rows and 128 to 1024 lanes each way.
+    With 128 output lanes the slice is not split."""
+    rows = th * tw
+    temps = vmem_bytes((rows, cit)) + vmem_bytes((rows, cot))
+    if cot > LANE:
+        temps = max(temps, vmem_bytes((rows, 4 * cit + 2 * LANE)))
+    return temps
 
 
 def tap_gemm_vmem(planes: int, n_taps: int, th: int, tw: int, halo_h: int,
                   halo_w: int, cit: int, cot: int) -> int:
-    return (vmem_bytes((planes, th + halo_h, window_w(tw, halo_w), cit))
+    return (_MOSAIC_SLACK
+            + vmem_bytes((planes, th + halo_h, window_w(tw, halo_w), cit))
             + vmem_bytes((n_taps, cit, cot), 2)
             + vmem_bytes((th, tw, cot), 2)
             + vmem_bytes((th * tw, cot))
@@ -133,10 +153,14 @@ def tap_gemm_vmem(planes: int, n_taps: int, th: int, tw: int, halo_h: int,
 
 def tap_wgrad_vmem(planes: int, n_taps: int, th: int, tw: int, halo_h: int,
                    halo_w: int, cit: int, cot: int) -> int:
-    return (vmem_bytes((planes, th + halo_h, window_w(tw, halo_w), cit))
+    return (_MOSAIC_SLACK
+            + vmem_bytes((planes, th + halo_h, window_w(tw, halo_w), cit))
             + vmem_bytes((th, tw, cot), 2)
             + vmem_bytes((n_taps, cit, cot), 3)     # out block x2 + acc
-            + _gemm_temps(th, tw, cit, cot) + vmem_bytes((cit, cot)))
+            + _gemm_temps(th, tw, cit, cot) + vmem_bytes((cit, cot))
+            # the dot contracts the slice's rows: Mosaic transposes it,
+            # about 2 KiB per contraction lane in compiles at 56-224 rows
+            + vmem_bytes((cit, 2 * LANE), 2))
 
 
 # ---------------------------------------------------------------------------

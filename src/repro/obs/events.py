@@ -36,8 +36,8 @@ KINDS: dict[str, tuple[str, str]] = {
         "mesh lowering drops/fallbacks (the dispatch_events names)"),
     "plan": (
         "kernels/ops.py, kernels/autotune.py",
-        "tile-plan outcomes per role (pallas/fallback) and autotune "
-        "hit/miss/stale/poisoned/measure_failed"),
+        "tile-plan outcomes per role (pallas/fallback/packed/wide) and "
+        "autotune hit/miss/stale/poisoned/measure_failed"),
     "fault": (
         "ft/inject.py",
         "every injected fault that fired (site, action, step, pattern)"),
