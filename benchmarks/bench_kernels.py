@@ -43,18 +43,8 @@ Pallas path now falls back, or a case's Pallas tap count grew
 (zero-skipping regressed -- the gate covers the transposed cases'
 ``taps.real`` identically).
 
-Schema 5 adds the measured-autotune surface (``repro.config.autotune``):
-``--autotune off|measure|cached`` and ``--plan-cache-dir`` set the config
-for the run, the record carries an ``autotune`` block (mode / top_k /
-reps / cache path), ``plan_time_us = {cold, warm}`` (total planning time
-for every case with all in-process caches dropped vs memoized -- in
-``measure`` mode "cold" includes on-device candidate timing, in
-``cached`` mode it is the persistent-cache read), each case's tile plans
-carry ``autotune = {autotuned, measured_us, candidates_timed, cache}``
-when a plan went through the tuner, and ``plan_cache_all_hits`` says
-every case's every pass resolved from the persistent cache.
-``--require-plan-cache-hits`` turns that into a hard gate (the CI smoke
-lane's warm second run).
+Schema 5 adds ``plan_time_us = {cold, warm}``: the total planning time
+for every case with the tile-plan caches dropped vs memoized.
 
 Schema 6 adds the telemetry-overhead columns (``repro.obs``): every case
 is re-measured through the SAME jitted ``jax.grad`` path with telemetry
@@ -89,7 +79,7 @@ from repro.core.conv import (conv2d, conv2d_transpose,      # noqa: E402
 from repro.core.convspec import ConvSpec, ConvTransposeSpec  # noqa: E402
 from repro.core.config import config                        # noqa: E402
 from repro.core.im2col_ref import ConvDims                  # noqa: E402
-from repro.kernels import autotune, ops                     # noqa: E402
+from repro.kernels import ops                               # noqa: E402
 
 CASES = [
     ConvDims(B=2, C=16, H_i=32, W_i=32, N=32, K_h=3, K_w=3, S=2, P_h=1, P_w=1),
@@ -386,11 +376,9 @@ def _all_plan_dims(cases, tcases) -> list[ConvDims]:
 
 
 def _measure_plan_time(cases, tcases) -> dict[str, float]:
-    """Total wall time (us) to plan EVERY case, cold (in-process plan
-    caches dropped: the analytic lru, the tuned-plan memo) then warm
-    (everything memoized).  Cold is where autotuning costs live: candidate
-    timing in ``measure`` mode, the persistent-cache read in ``cached``
-    mode.  Warm is the steady-state cost a training step sees."""
+    """Total wall time (us) to plan EVERY case, cold (the tile-plan lru
+    caches dropped) then warm (everything memoized).  Warm is the
+    steady-state cost a training step sees."""
     dims = _all_plan_dims(cases, tcases)
 
     def once():
@@ -400,28 +388,9 @@ def _measure_plan_time(cases, tcases) -> dict[str, float]:
         return (time.perf_counter() - t0) * 1e6
 
     ops.clear_tile_plan_cache()
-    autotune.clear_memo()
     cold = once()
     warm = once()
     return {"cold": round(cold, 1), "warm": round(warm, 1)}
-
-
-def _plan_cache_all_hits(record_cases) -> bool:
-    """True iff every tile plan of every case was served from the
-    persistent plan cache (``cache == "hit"``).  Vacuously False when
-    autotuning is off (no plan carries the annotation)."""
-    seen = False
-    for c in record_cases:
-        plan = c["plan"]
-        subs = [plan["forward"], plan["weight_grad"]]
-        if plan["input_grad"].get("fused"):
-            subs.append(plan["input_grad"])
-        for s in subs:
-            at = s.get("autotune")
-            if at is None or at["cache"] != "hit":
-                return False
-            seen = True
-    return seen
 
 
 def _json_record(rows, cases, trows=(), tcases=(),
@@ -461,10 +430,6 @@ def _json_record(rows, cases, trows=(), tcases=(),
         "schema": 6,
         "vmem_budget_bytes": config.vmem_budget_bytes,
         "interpret": config.interpret,
-        "autotune": {"mode": config.autotune,
-                     "top_k": config.autotune_top_k,
-                     "reps": config.autotune_reps,
-                     "cache_path": autotune.cache_path()},
         "plan_time_us": plan_time_us,
         "cases": record_cases,
         "plan_events": events,
@@ -472,7 +437,6 @@ def _json_record(rows, cases, trows=(), tcases=(),
         "pallas_path_all_cases": all(c["fits"] for c in record_cases),
         "auto_policy_all_pallas": all(c["auto_all_pallas"]
                                       for c in record_cases),
-        "plan_cache_all_hits": _plan_cache_all_hits(record_cases),
     }
 
 
@@ -564,31 +528,12 @@ def main():
                          "bimodality (the structural gates -- Pallas path, "
                          "auto policy, tap counts -- are tolerance-free); "
                          "tighten it for real-TPU comparisons")
-    ap.add_argument("--autotune", choices=("off", "measure", "cached"),
-                    default=None,
-                    help="set repro.config.autotune for this run "
-                         "(default: whatever the config/env already says)")
-    ap.add_argument("--plan-cache-dir", metavar="DIR", default=None,
-                    help="persistent plan-cache directory "
-                         "(repro.config.plan_cache_dir)")
-    ap.add_argument("--require-plan-cache-hits", action="store_true",
-                    help="exit non-zero unless EVERY case's every tile "
-                         "plan was served from the persistent plan cache "
-                         "(the CI smoke lane's warm second run)")
     args = ap.parse_args()
     enable_compile_cache()
-    updates = {}
-    if args.autotune is not None:
-        updates["autotune"] = args.autotune
-    if args.plan_cache_dir is not None:
-        updates["plan_cache_dir"] = args.plan_cache_dir
-    if updates:
-        config.update(**updates)
     cases = TINY_CASES if args.tiny else CASES
     tcases = TINY_TRANSPOSE_CASES if args.tiny else TRANSPOSE_CASES
     reps = 1 if args.tiny else 10
     ops.clear_tile_plan_cache()
-    autotune.clear_memo()
     ops.reset_plan_events()
     rows = run(cases=cases, reps=reps)
     trows = run_transpose(tcases=tcases, reps=reps)
@@ -618,14 +563,6 @@ def main():
                   f"auto_policy_all_pallas="
                   f"{record['auto_policy_all_pallas']}", file=sys.stderr)
             raise SystemExit(1)
-    if args.require_plan_cache_hits and not record["plan_cache_all_hits"]:
-        at_events = {k: v for k, v in record["plan_events"].items()
-                     if "_autotune_" in k}
-        print(f"FAIL: --require-plan-cache-hits: not every tile plan was "
-              f"served from the persistent plan cache "
-              f"(autotune events: {at_events}, mode="
-              f"{record['autotune']['mode']})", file=sys.stderr)
-        raise SystemExit(1)
     if args.compare:
         with open(args.compare) as f:
             baseline = json.load(f)

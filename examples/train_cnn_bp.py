@@ -94,17 +94,6 @@ def main():
     ap.add_argument("--batch", type=int, default=32)
     ap.add_argument("--lr", type=float, default=0.05)
     ap.add_argument("--acc-floor", type=float, default=0.9)
-    ap.add_argument("--autotune", default=None,
-                    choices=["off", "measure", "cached"],
-                    help="measured autotuning of the Pallas tile plans "
-                         "(repro.config.autotune)")
-    ap.add_argument("--plan-cache-dir", default=None,
-                    help="persistent plan-cache directory "
-                         "(repro.config.plan_cache_dir)")
-    ap.add_argument("--fault-spec", default=None,
-                    help="arm the fault injector (repro.config.fault_spec), "
-                         "e.g. 'pallas.*:raise@step3' -- see "
-                         "examples/train_chaos.py for the full chaos drill")
     ap.add_argument("--trace", default=None, metavar="PATH",
                     help="enable telemetry and write a Perfetto trace_event "
                          "JSON (repro.obs) to PATH")
@@ -113,19 +102,11 @@ def main():
                          "JSONL to PATH")
     args = ap.parse_args()
     enable_compile_cache()
-    if args.autotune is not None or args.plan_cache_dir is not None \
-            or args.fault_spec is not None or args.trace is not None \
-            or args.metrics is not None:
+    paths = {k: v for k, v in (("trace_path", args.trace),
+                               ("metrics_path", args.metrics)) if v}
+    if paths:
         from repro.core.config import config
-        config.update(**{k: v for k, v in
-                         (("autotune", args.autotune),
-                          ("plan_cache_dir", args.plan_cache_dir),
-                          ("fault_spec", args.fault_spec),
-                          ("telemetry", bool(args.trace or args.metrics)
-                           or None),
-                          ("trace_path", args.trace),
-                          ("metrics_path", args.metrics))
-                         if v is not None})
+        config.update(telemetry=True, **paths)
     if args.mode is not None:
         warnings.warn("--mode is deprecated; use --policy",
                       DeprecationWarning)
@@ -143,9 +124,6 @@ def main():
     t0 = time.perf_counter()
     for step in range(args.steps):
         ts = time.perf_counter()
-        if args.fault_spec:
-            from repro.ft import inject
-            inject.set_step(step)
         x, y = synthetic_task(rng, args.batch)
         with obs.trace.span("train:step", step=step):
             loss, g = grad_fn(params, x, y)

@@ -9,17 +9,17 @@ from repro.core.conv import (MODES, conv1d, conv1d_causal, conv2d,
                              conv_policy, conv_transpose_output_shape,
                              depthwise_causal_conv1d,
                              dispatch_events, make_dims, policy_decisions,
-                             policy_report, quarantined_engines,
-                             register_engine, reset_dispatch_events,
-                             resolve_policy, runtime_failures, spec_dims,
-                             transpose_dims, transpose_tap_counts)
+                             policy_report, register_engine,
+                             reset_dispatch_events, resolve_policy,
+                             runtime_failures, spec_dims, transpose_dims,
+                             transpose_tap_counts)
 
 __all__ = ["ConvDims", "ConvSpec", "ConvTransposeSpec", "EnginePolicy",
            "PASSES", "MODES",
            "conv2d", "conv2d_transpose", "conv2d_transpose_materialized",
            "conv1d", "conv1d_causal", "depthwise_causal_conv1d",
            "conv_policy", "conv_transpose_output_shape", "dispatch_events",
-           "policy_decisions", "quarantined_engines",
-           "reset_dispatch_events", "resolve_policy", "runtime_failures",
+           "policy_decisions", "reset_dispatch_events", "resolve_policy",
+           "runtime_failures",
            "policy_report", "register_engine", "make_dims", "spec_dims",
            "transpose_dims", "transpose_tap_counts"]

@@ -5,8 +5,6 @@ compiled program again, so it is a fixed path: ``JAX_COMPILATION_CACHE_DIR``
 when the environment sets it (jax reads that variable itself, and nothing
 here overrides it), otherwise ``<checkout>/.cache/jax``.  Entry points call
 :func:`enable_compile_cache` once at start-up; library code never does.
-The measured-plan cache (``kernels/autotune.py``) lives under the same
-directory.
 
 The cache key includes each op's metadata: the named-scope path that
 ``core/conv.py`` and ``kernels/ops.py`` put on every conv pass and its glue

@@ -10,7 +10,7 @@ env-var knobs that used to be read ad hoc across five modules
     from repro.core.config import config        # or: import repro; repro.config
 
     config.vmem_budget_bytes                    # read anywhere, any time
-    config.update(autotune="measure")           # permanent, validated
+    config.update(ssd_chunk=64)                 # permanent, validated
     with config.override(vmem_budget_bytes=1 << 20):
         ...                                     # scoped, restored on exit
 
@@ -18,11 +18,10 @@ Fields initialize ONCE from the environment (so launcher scripts that export
 ``REPRO_*`` before python starts keep working unchanged), and direct
 attribute assignment raises -- mutation goes through :meth:`GlobalConfig.
 update` / :meth:`GlobalConfig.override`, which validate values and
-invalidate the tile-plan/autotune caches when a plan-affecting field
-(``vmem_budget_bytes``, ``interpret``, the ``autotune*`` family,
-``plan_cache_dir``) changes.  That kills the pre-config footgun where
-mutating a module global (``ops.VMEM_BUDGET_BYTES``) relied on the lru key
-catching the change.
+invalidate the tile-plan caches when a plan-affecting field
+(``vmem_budget_bytes``, ``interpret``) changes.  That kills the pre-config
+footgun where mutating a module global (``ops.VMEM_BUDGET_BYTES``) relied
+on the lru key catching the change.
 
 Backward compatibility: mutating the environment AFTER import still works --
 each attribute read re-checks the raw env string against the snapshot taken
@@ -50,16 +49,6 @@ def _parse_bool(raw: str) -> bool:
 
 def _parse_optional_str(raw: str) -> str | None:
     return raw or None
-
-
-AUTOTUNE_MODES = ("off", "measure", "cached")
-
-
-def _check_autotune(v: Any) -> str:
-    if v not in AUTOTUNE_MODES:
-        raise ValueError(
-            f"autotune must be one of {AUTOTUNE_MODES}, got {v!r}")
-    return v
 
 
 def _check_positive_int(name: str) -> Callable[[Any], int]:
@@ -134,25 +123,6 @@ FIELDS: dict[str, _Field] = {
     "vmem_budget_bytes": _Field("REPRO_VMEM_BUDGET_BYTES", 14 * 1024 * 1024,
                                 int, _check_positive_int("vmem_budget_bytes"),
                                 plan_affecting=True),
-    # Measured autotuning of the tap-GEMM tile plans (kernels/autotune.py):
-    #   off     -- analytic tile search only (the historical behavior);
-    #   measure -- time the top-k analytic candidates on device, persist the
-    #              winner in the plan cache, reuse persisted winners;
-    #   cached  -- never time: use persisted winners when present, analytic
-    #              plans otherwise (production mode: zero tuning cost).
-    "autotune": _Field("REPRO_AUTOTUNE", "off", str, _check_autotune,
-                       plan_affecting=True),
-    "autotune_top_k": _Field("REPRO_AUTOTUNE_TOP_K", 4, int,
-                             _check_positive_int("autotune_top_k"),
-                             plan_affecting=True),
-    "autotune_reps": _Field("REPRO_AUTOTUNE_REPS", 3, int,
-                            _check_positive_int("autotune_reps"),
-                            plan_affecting=True),
-    # Plan-cache directory; None resolves next to jax's compilation cache
-    # (see kernels/autotune.py:default_cache_dir).
-    "plan_cache_dir": _Field("REPRO_PLAN_CACHE_DIR", None,
-                             _parse_optional_str, _check_optional_str,
-                             plan_affecting=True),
     # Mamba2 SSD chunk length (intra-chunk quadratic vs inter-chunk linear).
     "ssd_chunk": _Field("REPRO_SSD_CHUNK", 128, int,
                         _check_positive_int("ssd_chunk")),
@@ -171,7 +141,7 @@ FIELDS: dict[str, _Field] = {
                     _check_optional_str),
     # Deterministic fault injection (repro.ft.inject): ';'-separated rules
     # '<site-glob>:<action>[@stepN][~pP]', e.g.
-    # "pallas.*:raise@step3;grad.values:nan@step5".  None/"" disarms.  The
+    # "ckpt.write:raise@step3;grad.values:nan@step5".  None/"" disarms.  The
     # spec grammar is validated by the injector at arm time, so a bad spec
     # fails the update() that sets it (when the injector is importable).
     "fault_spec": _Field("REPRO_FAULT_SPEC", None, _parse_optional_str,
@@ -203,15 +173,12 @@ _OBS_FIELDS = ("telemetry", "trace_path", "metrics_path")
 
 
 def _invalidate_plan_caches() -> None:
-    """Drop every memoized tile plan and tuned-plan memo.  Lazy through
-    sys.modules: config must stay importable before (and without) the
-    kernel stack, and must not create an import cycle with it."""
+    """Drop every memoized tile plan.  Lazy through sys.modules: config
+    must stay importable before (and without) the kernel stack, and must
+    not create an import cycle with it."""
     ops = sys.modules.get("repro.kernels.ops")
     if ops is not None:
         ops.clear_tile_plan_cache()
-    autotune = sys.modules.get("repro.kernels.autotune")
-    if autotune is not None:
-        autotune.clear_memo()
 
 
 def _sync_fault_injector(import_now: bool = False) -> None:
@@ -298,8 +265,8 @@ class GlobalConfig:
             f"or the config.override(...) context manager")
 
     def update(self, **kw) -> None:
-        """Validated permanent update; invalidates the tile-plan and tuned-
-        plan caches when a plan-affecting field actually changes."""
+        """Validated permanent update; invalidates the tile-plan caches
+        when a plan-affecting field actually changes."""
         unknown = set(kw) - set(FIELDS)
         if unknown:
             raise ValueError(

@@ -39,17 +39,9 @@ strongest capable engine -- the substitution is recorded, never silent:
 :func:`dispatch_events` counts the engine *actually used* per pass and
 :func:`policy_decisions` keeps the per-decision reasons.
 
-The same ladder also runs at EXECUTION time (:func:`_execute`): an engine
-that *raises* mid-pass is re-dispatched down the capability chain instead
-of killing the step.  The failure edge is recorded
-(``"pass:failed->survivor"`` in :func:`dispatch_events`, exception class in
-:func:`runtime_failures`), the failing engine is quarantined for that
-(pass, geometry) and probed for recovery after
-:data:`QUARANTINE_PROBE_AFTER` dispatches, and a crashing pallas launch
-poison-marks its plan-cache entry (``autotune.poison_plan``) so
-``autotune="cached"`` cannot re-crash on restart.  ``lax`` is the terminal
-anchor: never quarantined, and if every engine fails the first exception
-propagates.
+An engine that RAISES while it runs a pass is not replaced: the exception
+propagates out of the pass (:func:`_execute`), so a failing kernel is
+surfaced, never hidden behind another engine's answer.
 
 Dilation is lowered per engine, declared by the ``native_dilation``
 capability flag.  Engines WITHOUT it get a dispatch-level kernel
@@ -94,7 +86,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import bpim2col, im2col_ref, phase_decomp
-from repro.core.config import config
 from repro.obs import events as obs_events
 from repro.obs import trace as obs_trace
 from repro.core.convspec import (AUTO, ConvSpec, ConvTransposeSpec,
@@ -436,8 +427,6 @@ def policy_decisions() -> list[dict]:
 def reset_dispatch_events() -> None:
     DISPATCH_EVENTS.clear()
     POLICY_DECISIONS.clear()
-    RUNTIME_FAILURES.clear()
-    _QUARANTINE.clear()
     # Keep the bus-backed view (obs.events.counters("dispatch")) in lockstep
     # with the legacy dict under every reset pattern (no-op when off).
     obs_events.drop("dispatch")
@@ -548,38 +537,14 @@ def resolve_engine(requested: str, pass_name: str, d: ConvDims,
 
 
 # ---------------------------------------------------------------------------
-# Runtime graceful degradation: execute-with-fallback, quarantine, probes
+# Execution: one resolution, one engine, one recorded dispatch
 # ---------------------------------------------------------------------------
 
-#: structured log of runtime engine failures (bounded like the decisions).
-RUNTIME_FAILURES: list[dict] = []
-
-#: a quarantined (pass, engine, geometry) is skipped for this many
-#: dispatches, then probed for recovery (each dispatch is one trace -- one
-#: step when the caller is eager, one retrace boundary under jit).
-QUARANTINE_PROBE_AFTER = 3
-
-#: (pass_key, engine, d) -> dispatches skipped since quarantine began.
-_QUARANTINE: dict[tuple, int] = {}
-
-
 def runtime_failures() -> list[dict]:
-    """Every runtime engine failure absorbed by the degradation layer:
-    pass, engine, exception class, the survivor that served the pass, and
-    the geometry.  Reset by :func:`reset_dispatch_events`."""
-    return list(RUNTIME_FAILURES)
-
-
-def quarantined_engines() -> list[dict]:
-    """The currently quarantined (pass, engine, geometry) entries and how
-    many dispatches each has been skipped for."""
-    return [{"pass": k[0], "engine": k[1], "dims": k[2], "skips": v}
-            for k, v in sorted(_QUARANTINE.items(),
-                               key=lambda kv: (kv[0][0], kv[0][1]))]
-
-
-def clear_quarantine() -> None:
-    _QUARANTINE.clear()
+    """Always ``[]``: an engine that raises propagates out of the pass
+    (:func:`_execute`), so no failure is ever absorbed.  Kept because the
+    chip benchmark's counters line reports it."""
+    return []
 
 
 def _record_event(key: str) -> None:
@@ -591,113 +556,28 @@ def _dims_key(d: ConvDims) -> tuple:
     return (d.B, d.C, d.H_i, d.W_i, d.N, d.K_h, d.K_w, d.s_h, d.s_w)
 
 
-def _runtime_chain(name: str, d: ConvDims) -> list[str]:
-    """``name`` followed by the capability-ordered engines below it --
-    the same ``bp_phase -> lax`` ladder plan-time fallback walks, with
-    ``lax`` always terminal."""
-    chain = [name]
-    for cand in _FALLBACK_CHAIN:
-        if cand != name and cand in ENGINES and \
-                _capability_gap(ENGINES[cand], d) is None:
-            chain.append(cand)
-    if "lax" not in chain:
-        chain.append("lax")
-    return chain
-
-
-def _poison_plan_entry(pass_name: str, transposed: bool, d: ConvDims) -> None:
-    """Poison-mark the plan-cache entry that fed a crashing pallas launch
-    (best effort -- poisoning must never mask the degradation itself)."""
-    if config.autotune == "off":
-        return
-    role = _TRANSPOSE_ROLE[pass_name] if transposed else pass_name
-    try:
-        from repro.kernels import autotune
-        autotune.poison_plan(role, d)
-    except Exception:
-        pass
-
-
 def _execute(pass_name: str, requested: str, d: ConvDims, transposed: bool,
              run: Callable):
-    """Resolve one conv pass and execute it, degrading at run time only
-    while a fault is armed.
+    """Resolve one conv pass (:func:`resolve_engine`) and execute it.
 
-    ``run(engine)`` performs the pass.  With no fault armed
-    (``config.fault_spec`` unset) an exception from the engine propagates:
-    a kernel that fails to trace, lower or run is a bug to surface, and
-    serving the pass from another engine would hide which engine ran.
-    With a fault armed, an exception re-dispatches down the
-    capability-ordered fallback chain: the failure
-    is recorded (``dispatch_events`` gains ``"pass:failed->survivor"``,
-    :func:`runtime_failures` keeps the exception class), the failing
-    engine is QUARANTINED for this (pass, geometry) -- subsequent
-    dispatches skip it for :data:`QUARANTINE_PROBE_AFTER` rounds, then
-    probe it once; a successful probe lifts the quarantine
-    (``"pass:engine:recovered"``), a failed one re-arms it -- and a
-    crashing pallas launch poison-marks its plan-cache entry so
-    ``autotune="cached"`` cannot re-crash on restart.  ``lax`` is the
-    terminal anchor: it is never quarantined, and when every engine in
-    the chain fails the FIRST exception propagates (nothing to degrade
-    to).  The no-failure path records exactly what it always did: one
-    dispatch event, one policy decision.
-    """
+    ``run(engine)`` performs the pass under the pass's trace scope.  The
+    engine actually used is counted in :func:`dispatch_events` and its
+    decision logged in :func:`policy_decisions`.  An exception from the
+    engine propagates: a kernel that fails to trace, lower or run is a bug
+    to surface, and serving the pass from another engine would hide which
+    engine ran."""
     name, reason = resolve_engine(requested, pass_name, d, transposed)
     # Transposed-conv passes count under their own keys ("forward_T:pallas")
     # so a decoder's dispatch is distinguishable from its encoder's.
     pkey = f"{pass_name}{'_T' if transposed else ''}"
-    first_exc = None
-    failures: list[dict] = []
-    for cand in _runtime_chain(name, d):
-        qkey = (pkey, cand, _dims_key(d))
-        probing = False
-        if qkey in _QUARANTINE and cand != "lax":
-            _QUARANTINE[qkey] += 1
-            if _QUARANTINE[qkey] <= QUARANTINE_PROBE_AFTER:
-                _record_event(f"{pkey}:{cand}:quarantined")
-                continue
-            probing = True
-            _record_event(f"{pkey}:{cand}:probe")
-        try:
-            with obs_trace.conv_pass(pkey, cand, d):
-                out = run(ENGINES[cand])
-        except Exception as e:
-            if not config.fault_spec:
-                raise
-            if first_exc is None:
-                first_exc = e
-            if cand != "lax":
-                _QUARANTINE[qkey] = 0
-            fail = {"pass": pkey, "engine": cand,
-                    "exception": type(e).__name__, "error": str(e)[:200],
-                    "survivor": None, "probe": probing,
-                    "dims": _dims_key(d)}
-            failures.append(fail)
-            if len(RUNTIME_FAILURES) < _MAX_DECISIONS:
-                RUNTIME_FAILURES.append(fail)
-            if cand == "pallas":
-                _poison_plan_entry(pass_name, transposed, d)
-            continue
-        if probing:
-            del _QUARANTINE[qkey]
-            _record_event(f"{pkey}:{cand}:recovered")
-        for fail in failures:
-            fail["survivor"] = cand
-            _record_event(f"{pkey}:{fail['engine']}->{cand}")
-            reason = (f"runtime degradation: {fail['engine']} raised "
-                      f"{fail['exception']}; quarantined, {cand} survives")
-        _record_event(f"{pkey}:{cand}")
-        if len(POLICY_DECISIONS) < _MAX_DECISIONS:
-            POLICY_DECISIONS.append({
-                "pass": pass_name, "requested": requested, "engine": cand,
-                "reason": reason, "transpose": transposed,
-                "dims": _dims_key(d)})
-        return out
-    if first_exc is not None:
-        raise first_exc
-    raise RuntimeError(
-        f"every engine for {pkey} is quarantined for dims {_dims_key(d)}; "
-        f"chain {_runtime_chain(name, d)}")
+    with obs_trace.conv_pass(pkey, name, d):
+        out = run(ENGINES[name])
+    _record_event(f"{pkey}:{name}")
+    if len(POLICY_DECISIONS) < _MAX_DECISIONS:
+        POLICY_DECISIONS.append({
+            "pass": pass_name, "requested": requested, "engine": name,
+            "reason": reason, "transpose": transposed, "dims": _dims_key(d)})
+    return out
 
 
 def _validate_policy(policy: EnginePolicy) -> EnginePolicy:
@@ -829,9 +709,9 @@ def _conv2d_fwd(x, w, spec, policy):
 
 
 def _run_wgrad(x, dy, d, eng, spec):
-    """One engine's complete weight-grad pass, un-dilation included --
-    the degradation unit must cover the whole engine-dependent pipeline,
-    since the survivor may differ in ``native_dilation``."""
+    """One engine's complete weight-grad pass, un-dilation included:
+    engines differ in ``native_dilation``, so the un-dilation belongs to
+    the pass that :func:`_execute` runs."""
     dw = _weight_grad(x, dy, d, eng, spec.groups)
     if not eng.native_dilation:
         dw = _undilate_dweight(dw, spec)

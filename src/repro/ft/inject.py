@@ -1,14 +1,14 @@
 """Deterministic, config-driven fault injection (``repro.config.fault_spec``).
 
-Every failure domain in the stack registers a NAMED SITE through the one
-:func:`fault_point` helper -- the Pallas kernel launches, the plan-cache
-read/write, the autotune timing harness, the checkpoint writer/reader,
-the gradient values of the train step, and the continuous serving
+Every failure domain with recovery code registers a NAMED SITE through
+the one :func:`fault_point` helper -- the checkpoint writer/reader
+(restore falls back to an older step), the gradient values of the train
+step (the NaN guard skips the update), and the continuous serving
 engine's per-request prefill / per-lane decode (a crashing lane
 finalizes that request with ``status="failed"`` instead of killing the
 batch).  A fault spec arms rules against those sites:
 
-    config.update(fault_spec="pallas.*:raise@step3;grad.values:nan@step5")
+    config.update(fault_spec="ckpt.write:raise@step3;grad.values:nan@step5")
 
 Grammar (``;``-separated rules)::
 
@@ -27,21 +27,18 @@ Grammar (``;``-separated rules)::
 Zero overhead when disarmed: :func:`fault_point` is a single ``is None``
 check, so the production hot path pays one attribute read per site.  The
 injector records every firing (:func:`fired_events`) and every site it saw
-while armed (:func:`seen_sites`) so CI can assert both the degradation
+while armed (:func:`seen_sites`) so CI can assert both the recovery
 behaviour and the site coverage.
 
 The config singleton re-arms the injector whenever ``fault_spec`` /
 ``fault_seed`` change (``config.update`` or the deprecated env mutation),
 and this module syncs once at import, so either import order works.
 
-Step-targeted rules and jit: dispatch-level sites fire at TRACE time, so a
-``@stepN`` rule only hits a jitted train step if that step triggers a
-(re)trace -- which is exactly the realistic failure (Mosaic lowering
-errors happen at compile time).  Chaos drivers that want per-step dispatch
-faults run the grad function eagerly.  ``grad.values`` is different: the
-train step builds the NaN injection INTO the jitted graph
+Step-targeted rules and jit: ``grad.values`` sits inside the jitted train
+step, which builds the NaN injection INTO the graph
 (:func:`value_fault_steps` + :func:`nan_factor`), so it fires on the exact
-step regardless of jit caching.
+step regardless of jit caching.  The checkpoint and serve sites run on
+the host and fire when they are reached.
 """
 
 from __future__ import annotations
@@ -58,12 +55,6 @@ from repro.obs import events as obs_events
 #: failure domain means adding its name here -- the coverage test asserts
 #: the two stay in sync by exercising each domain.
 KNOWN_SITES = frozenset({
-    "pallas.forward.launch",      # kernels/ops.py: forward tap-GEMM launch
-    "pallas.input_grad.launch",   # kernels/ops.py: fused phased launch
-    "pallas.weight_grad.launch",  # kernels/ops.py: tap-wgrad launch
-    "plan_cache.read",            # kernels/autotune.py: persistent store read
-    "plan_cache.write",           # kernels/autotune.py: atomic store write
-    "autotune.measure",           # kernels/autotune.py: candidate timing
     "ckpt.write",                 # ckpt/checkpoint.py: manifest+leaf writer
     "ckpt.read",                  # ckpt/checkpoint.py: restore
     "grad.values",                # train loops: the gradient pytree itself

@@ -36,16 +36,6 @@ entirely.  ``tile_plan_cache_info()`` exposes hit counts;
 fallback outcomes (one event per unique shape/budget) for benchmarks & CI,
 and ``plan_seconds()`` the host seconds those unique resolutions took.
 
-When ``repro.config.autotune`` is not ``"off"``, the public planners
-(:func:`forward_plan` / :func:`weight_grad_plan` / :func:`input_grad_plan`)
-route through ``repro.kernels.autotune``: the analytic search keeps its
-role (feasibility + fallback/event accounting), but the tile actually
-dispatched may be a MEASURED winner -- the top-k analytic
-candidates timed on device, persisted in an on-disk plan cache.  The
-``"auto"`` engine resolver and every ``conv2d`` dispatch consult tuned
-plans exactly as they consult analytic ones, because they all go through
-these three entry points.
-
 ``repro.config.interpret`` is worked out from the backend on first use:
 the kernels are compiled with Mosaic on a TPU and run in the Pallas
 interpreter on the CPU (where the test-suite runs them).  On a TPU an
@@ -72,7 +62,6 @@ import jax.numpy as jnp
 from repro.core.config import config
 from repro.core.im2col_ref import ConvDims, rot180, zero_insert, zero_pad
 from repro.core import phase_decomp
-from repro.ft.inject import fault_point
 from repro.kernels import tap_gemm as tg
 from repro.obs import events as obs_events
 from repro.obs.trace import GLUE_SCOPE
@@ -113,10 +102,9 @@ def plan_events() -> dict[str, int]:
 
 def plan_seconds() -> float:
     """Host seconds spent resolving tile plans since the last
-    :func:`reset_plan_events`: the analytic search and the autotuner, on a
-    miss of their caches only (a cached plan costs nothing).  Planning
-    runs while jax traces a program, so this is set-up time, never a
-    step's."""
+    :func:`reset_plan_events`: the analytic search, on a miss of its
+    caches only (a cached plan costs nothing).  Planning runs while jax
+    traces a program, so this is set-up time, never a step's."""
     return _PLAN_CLOCK["seconds"]
 
 
@@ -406,15 +394,6 @@ class TilePlan:
     contraction is lane-packed (:class:`LanePack`); the kernel then runs
     ``pack.taps`` over the packed source, and ``halo_*`` and
     ``bytes_needed`` describe that packed window.
-
-    The trailing autotune fields are metadata only (they do not change the
-    dispatch): ``autotuned`` marks a MEASURED winner from
-    ``repro.kernels.autotune`` rather than the analytic search,
-    ``measured_us`` its best-of-reps wall-clock, ``candidates_timed`` how
-    many analytic candidates were raced, and ``cache`` whether the winner
-    came from the persistent plan cache (``"hit"``), was tuned fresh
-    (``"miss"``) or replaced an invalid persisted entry (``"stale"``).
-    Analytic plans leave them at their defaults.
     """
     fits: bool
     cin_pad: int
@@ -430,10 +409,6 @@ class TilePlan:
     halo_w: int
     bytes_needed: int
     pack: LanePack | None = None
-    autotuned: bool = False
-    measured_us: float = -1.0
-    candidates_timed: int = 0
-    cache: str = ""
 
     @property
     def spatial_splits(self) -> int:
@@ -443,12 +418,6 @@ class TilePlan:
     def kernel_taps(self) -> tuple[tuple[int, int, int], ...]:
         """The tap table the kernel runs: packed when ``pack`` is set."""
         return self.taps if self.pack is None else self.pack.taps
-
-    @property
-    def tile_key(self) -> tuple[int, int, int, int]:
-        """The persisted identity of one candidate: what the autotuner
-        stores and what :func:`plan_from_tile` revalidates."""
-        return (self.oh_tile, self.ow_tile, self.cin_tile, self.cout_tile)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -647,23 +616,6 @@ def _phase_plan_of(d: ConvDims, geom, tile: TilePlan) -> PhasePlan:
                      tile)
 
 
-def _autotuned(role: str, d: ConvDims, budget: int, analytic):
-    """Route one planner resolution through the measured autotuner when
-    ``config.autotune`` enables it.  The analytic result keeps ownership of
-    feasibility (fits=False / None never gets tuned -- there is nothing to
-    race) and of the planned-vs-fallback event accounting."""
-    if config.autotune == "off":
-        return analytic
-    if analytic is None or not getattr(analytic, "fits", True):
-        return analytic
-    from repro.kernels import autotune
-    memo = autotune.memoized(role, d, budget)
-    if memo is not None:
-        return memo
-    with _plan_clock():
-        return autotune.tuned_plan(role, d, budget, analytic)
-
-
 def _analytic_plan(role: str, d: ConvDims, budget: int) -> TilePlan:
     with _plan_clock():
         g = _geom(role, d)
@@ -679,7 +631,7 @@ def _analytic_plan(role: str, d: ConvDims, budget: int) -> TilePlan:
 
 def forward_plan(d: ConvDims, budget: int | None = None) -> TilePlan:
     d, budget = _canonical(d), _budget_or_default(budget)
-    return _autotuned("forward", d, budget, _forward_plan(d, budget))
+    return _forward_plan(d, budget)
 
 
 @functools.lru_cache(maxsize=4096)
@@ -689,7 +641,7 @@ def _forward_plan(d: ConvDims, budget: int) -> TilePlan:
 
 def weight_grad_plan(d: ConvDims, budget: int | None = None) -> TilePlan:
     d, budget = _canonical(d), _budget_or_default(budget)
-    return _autotuned("weight_grad", d, budget, _weight_grad_plan(d, budget))
+    return _weight_grad_plan(d, budget)
 
 
 @functools.lru_cache(maxsize=4096)
@@ -700,7 +652,7 @@ def _weight_grad_plan(d: ConvDims, budget: int) -> TilePlan:
 def input_grad_plan(d: ConvDims,
                     budget: int | None = None) -> PhasePlan | None:
     d, budget = _canonical(d), _budget_or_default(budget)
-    return _autotuned("input_grad", d, budget, _input_grad_plan(d, budget))
+    return _input_grad_plan(d, budget)
 
 
 @functools.lru_cache(maxsize=4096)
@@ -714,7 +666,7 @@ def _input_grad_plan(d: ConvDims, budget: int) -> PhasePlan | None:
     return _phase_plan_of(d, _input_grad_geom(d), tile)
 
 
-#: the three tap-GEMM pass roles the planners (and the autotuner) speak.
+#: the three tap-GEMM pass roles the planners speak.
 PLAN_ROLES = ("forward", "weight_grad", "input_grad")
 
 
@@ -759,46 +711,19 @@ def shard_halo(d: ConvDims) -> tuple[tuple[int, int], tuple[int, int]]:
 
 
 def plan_candidates(role: str, d: ConvDims, budget: int | None = None,
-                    k: int | None = None):
-    """The autotuner's shortlist: up to ``k`` analytically FITTING plans,
-    best ranked first (first element == the analytic winner), across
-    spatial and channel tiles alike.  ``input_grad`` candidates are full
-    :class:`PhasePlan` objects sharing one geometry.  Pure and unmemoized;
-    records no plan events."""
+                    k: int = 4):
+    """Up to ``k`` FITTING plans, best ranked first (first element == the
+    planner's own plan), across spatial and channel tiles alike.
+    ``input_grad`` candidates are full :class:`PhasePlan` objects sharing
+    one geometry.  Pure and unmemoized; records no plan events.  Tests
+    pass these through the kernels' ``plan=`` argument to check that every
+    tiling computes the same answer."""
     d, budget = _canonical(d), _budget_or_default(budget)
-    k = config.autotune_top_k if k is None else k
     g = _geom(role, d)
     plans = [g.plan(*t) for t in _fitting_tiles(g, budget)[:k]]
     if role == "input_grad":
         return [_phase_plan_of(d, g.phase, t) for t in plans]
     return plans
-
-
-def plan_from_tile(role: str, d: ConvDims, budget: int | None,
-                   tile) -> TilePlan | PhasePlan | None:
-    """Rebuild a dispatchable plan from a PERSISTED candidate identity
-    ``(oh_tile, ow_tile, cin_tile, cout_tile)``, revalidating it against
-    the current geometry, tiling rules and budget.  Returns None when the
-    tile is no longer valid (plan-cache entry gone stale: code changed the
-    geometry, the budget shrank, or the entry is garbage) -- the caller
-    re-tunes."""
-    d, budget = _canonical(d), _budget_or_default(budget)
-    g = _geom(role, d)
-    try:
-        th, tw, cit, cot = (int(v) for v in tile)
-    except (TypeError, ValueError):
-        return None
-    if (th, tw) not in set(_spatial_candidates(g.oh, g.ow)):
-        return None
-    if cit not in g.cin_tiles or cot not in g.cout_tiles:
-        return None
-    bytes_needed = g.cost(th, tw, cit, cot)
-    if bytes_needed > budget:
-        return None
-    plan = g.plan(th, tw, cit, cot, bytes_needed)
-    if role == "input_grad":
-        return _phase_plan_of(d, g.phase, plan)
-    return plan
 
 
 _PLANNERS = {"forward_plan": _forward_plan,
@@ -835,11 +760,6 @@ def plan_report(d: ConvDims, budget: int | None = None) -> dict[str, object]:
             t["pack"] = {"slots": sum(len(s) for s in p.pack.slots),
                          "packed_taps": len(p.pack.taps),
                          "lane_fill": p.pack.lane_fill}
-        if p.cache:        # the plan went through the autotuner
-            t["autotune"] = {"autotuned": p.autotuned,
-                             "measured_us": p.measured_us,
-                             "candidates_timed": p.candidates_timed,
-                             "cache": p.cache}
         return t
     f = forward_plan(d, budget)
     wg = weight_grad_plan(d, budget)
@@ -869,7 +789,7 @@ def conv2d_forward(x: jax.Array, w: jax.Array, d: ConvDims,
     kernel (``k_taps_h x k_taps_w`` spatial extent); when ``d`` carries a
     dilation the tap table skips the zero positions instead of the kernel
     being materialized to ``K_h x K_w``.  ``plan`` overrides the planner
-    (the autotuner races explicit candidate plans through here)."""
+    (:func:`plan_candidates`)."""
     assert w.shape[-2:] == (d.k_taps_h, d.k_taps_w), (w.shape, d)
     if plan is None:
         plan = forward_plan(d)
@@ -878,7 +798,6 @@ def conv2d_forward(x: jax.Array, w: jax.Array, d: ConvDims,
             x, w, (d.s_h, d.s_w), [(d.P_h, d.p_h_hi), (d.P_w, d.p_w_hi)],
             rhs_dilation=(d.D_h, d.D_w),
             dimension_numbers=("NCHW", "OIHW", "NCHW"))
-    fault_point("pallas.forward.launch")
     with jax.named_scope(GLUE_SCOPE):
         xp = zero_pad(x, d.P_h, d.P_w, d.p_h_hi, d.p_w_hi)
         wt = w.transpose(2, 3, 1, 0).reshape(d.k_taps_h * d.k_taps_w, d.C,
@@ -909,13 +828,12 @@ def conv2d_input_grad(dy: jax.Array, w: jax.Array, d: ConvDims,
     """Input grad through ONE fused tap-GEMM launch.  ``w`` is the COMPACT
     kernel; the per-phase tap tables index straight into ``rot180(w)``
     (dilation's zero taps were dropped at plan time).  ``plan`` overrides
-    the planner (autotune candidate racing)."""
+    the planner (:func:`plan_candidates`)."""
     assert w.shape[-2:] == (d.k_taps_h, d.k_taps_w), (w.shape, d)
     pp = input_grad_plan(d) if plan is None else plan
     if pp is None:
         w_eff = zero_insert(w, (d.D_h, d.D_w)) if d.has_dilation else w
         return phase_decomp.input_grad_phase(dy, w_eff, d)
-    fault_point("pallas.input_grad.launch")
     tile = pp.tile
     with jax.named_scope(GLUE_SCOPE):
         wf = rot180(w)                             # (N, C, k_taps, k_taps)
@@ -957,13 +875,12 @@ def conv2d_weight_grad(x: jax.Array, dy: jax.Array, d: ConvDims,
     REAL kernel tap, returned at the compact ``k_taps_h x k_taps_w``
     extent (a dilated kernel's zero taps get no gradient computed at
     all -- they would be discarded anyway).  ``plan`` overrides the
-    planner (autotune candidate racing)."""
+    planner (:func:`plan_candidates`)."""
     if plan is None:
         plan = weight_grad_plan(d)
     if not plan.fits:
         dw = phase_decomp.weight_grad_phase(x, dy, d)   # effective extent
         return dw[..., ::d.D_h, ::d.D_w] if d.has_dilation else dw
-    fault_point("pallas.weight_grad.launch")
     with jax.named_scope(GLUE_SCOPE):
         xp = zero_pad(x, d.P_h, d.P_w, d.p_h_hi, d.p_w_hi)
         src = (_phase_split(_to_nhwc(xp), (d.s_h, d.s_w))

@@ -96,23 +96,13 @@ def main(argv=None, history: list | None = None):
                          "channel/spatial sharding with halo exchange; "
                          "layers the mesh cannot shard fall back with a "
                          "recorded reason")
-    ap.add_argument("--autotune", default=None,
-                    choices=["off", "measure", "cached"],
-                    help="measured autotuning of the Pallas tile plans "
-                         "(repro.config.autotune): 'measure' times the "
-                         "top-k candidates and persists the winners, "
-                         "'cached' reuses persisted winners without timing")
-    ap.add_argument("--plan-cache-dir", default=None,
-                    help="persistent plan-cache directory "
-                         "(repro.config.plan_cache_dir; default: next to "
-                         "jax's compilation cache)")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--fault-spec", default=None,
                     help="arm the fault injector (repro.config.fault_spec), "
-                         "e.g. 'pallas.*:raise@step3;grad.values:nan@step5'")
+                         "e.g. 'ckpt.write:raise@step3;grad.values:nan@step5'")
     ap.add_argument("--trace", default=None, metavar="PATH",
                     help="enable telemetry and write a Chrome/Perfetto "
                          "trace_event JSON of the run (repro.obs.trace) "
@@ -137,15 +127,10 @@ def main(argv=None, history: list | None = None):
     args = ap.parse_args(argv)
     enable_compile_cache()
 
-    if args.autotune is not None or args.plan_cache_dir is not None \
-            or args.fault_spec is not None or args.trace is not None \
+    if args.fault_spec is not None or args.trace is not None \
             or args.metrics is not None:
         from repro.core.config import config
         updates = {}
-        if args.autotune is not None:
-            updates["autotune"] = args.autotune
-        if args.plan_cache_dir is not None:
-            updates["plan_cache_dir"] = args.plan_cache_dir
         if args.fault_spec is not None:
             updates["fault_spec"] = args.fault_spec
         if args.trace is not None:

@@ -56,7 +56,7 @@ def sync_from_config() -> None:
 #: sys.modules.get keeps reset_all free of heavy imports: a module that
 #: was never imported has nothing to reset.
 _RESET_SURFACES: tuple[tuple[str, tuple[str, ...]], ...] = (
-    ("repro.core.conv", ("reset_dispatch_events", "clear_quarantine")),
+    ("repro.core.conv", ("reset_dispatch_events",)),
     ("repro.kernels.ops", ("reset_plan_events",)),
     ("repro.ft.inject", ("reset_events",)),
     ("repro.ckpt.checkpoint", ("reset_skipped_checkpoints",)),
@@ -65,10 +65,10 @@ _RESET_SURFACES: tuple[tuple[str, tuple[str, ...]], ...] = (
 
 def reset_all() -> None:
     """One reset covering every introspection surface in the repo: the
-    legacy counters (dispatch/plan/fault/quarantine/checkpoint) and the
-    obs bus/trace/metrics window.  Used by the test suite's autouse
-    fixture; deliberately does NOT clear the tile-plan or autotune caches
-    (those are plan state, not introspection state)."""
+    legacy counters (dispatch/plan/fault/checkpoint) and the obs
+    bus/trace/metrics window.  Used by the test suite's autouse fixture;
+    deliberately does NOT clear the tile-plan caches (those are plan
+    state, not introspection state)."""
     for mod_name, fns in _RESET_SURFACES:
         mod = sys.modules.get(mod_name)
         if mod is not None:

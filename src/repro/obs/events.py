@@ -32,12 +32,11 @@ import time
 KINDS: dict[str, tuple[str, str]] = {
     "dispatch": (
         "core/conv.py, dist/conv_parallel.py",
-        "engine dispatch, degradation, quarantine/probe/recover, and "
-        "mesh lowering drops/fallbacks (the dispatch_events names)"),
+        "engine dispatch per pass and mesh lowering drops/fallbacks "
+        "(the dispatch_events names)"),
     "plan": (
-        "kernels/ops.py, kernels/autotune.py",
-        "tile-plan outcomes per role (pallas/fallback/packed/wide) and "
-        "autotune hit/miss/stale/poisoned/measure_failed"),
+        "kernels/ops.py",
+        "tile-plan outcomes per role (pallas/fallback/packed/wide)"),
     "fault": (
         "ft/inject.py",
         "every injected fault that fired (site, action, step, pattern)"),

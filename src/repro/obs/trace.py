@@ -2,9 +2,9 @@
 
 Spans wrap the hot structural moments of a run -- conv dispatch per
 pass/engine (annotated with the ConvDims geometry, ``taps{real,
-materialized}``, ``skip_ratio`` and modeled ``bytes_moved``), autotune
-candidate timing, mesh halo ``ppermute`` exchanges, checkpoint writes and
-serve prefill/insert/decode steps -- and export as a single
+materialized}``, ``skip_ratio`` and modeled ``bytes_moved``), mesh halo
+``ppermute`` exchanges, checkpoint writes and serve prefill/insert/decode
+steps -- and export as a single
 ``{"traceEvents": [...]}`` file that chrome://tracing and ui.perfetto.dev
 load directly.  Each span also passes through
 ``jax.profiler.TraceAnnotation`` so the same names line up inside XLA
@@ -19,7 +19,7 @@ per (pid, tid) with microsecond ``ts`` from ``perf_counter``, so nesting
 is positional and ``scripts/validate_trace.py`` can check balance.
 Because conv dispatch happens at jax TRACE time, conv spans measure
 trace/compile-side dispatch, not steady-state device time -- which is
-exactly where the degradation ladder and plan lookups live.
+exactly where engine resolution and plan lookups live.
 
 The device side of a conv pass is its named scope instead
 (:func:`pass_scope`, entered with the span by :func:`conv_pass`): an

@@ -34,7 +34,7 @@ def rng():
 @pytest.fixture(autouse=True)
 def _clean_introspection():
     """Every test starts with clean introspection state everywhere -- the
-    legacy counters (dispatch/quarantine/plan/fault/checkpoint) and the
+    legacy counters (dispatch/plan/fault/checkpoint) and the
     obs bus/trace/metrics window -- via the one covering reset."""
     from repro import obs
     obs.reset_all()

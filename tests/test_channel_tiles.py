@@ -214,20 +214,25 @@ def _lax(x, w, dy, d: ConvDims):
     return (y, *vjp(dy))
 
 
+def _key(plan) -> tuple[int, int, int, int]:
+    """A plan's tiling: ``(oh_tile, ow_tile, cin_tile, cout_tile)``."""
+    t = _tile(plan)
+    return (t.oh_tile, t.ow_tile, t.cin_tile, t.cout_tile)
+
+
 def _channel_variants(role: str, d: ConvDims):
     """The planner's own plan, then the same spatial tile with one
     contraction step and the narrowest output tiles, and with the
-    narrowest contraction tiles and one output step."""
+    narrowest contraction tiles and one output step (None where no such
+    plan fits)."""
     own = _plan(role, d)
-    th, tw, *_ = _tile(own).tile_key
+    th, tw, _, _ = _key(own)
     t = _tile(own)
     narrow_in, narrow_out = LANE, (LANE if t.cout_pad > LANE
                                    else t.cout_pad)
-    return [own,
-            ops.plan_from_tile(role, d, None, (th, tw, t.cin_pad,
-                                               narrow_out)),
-            ops.plan_from_tile(role, d, None, (th, tw, narrow_in,
-                                               t.cout_pad))]
+    fitting = {_key(p): p for p in ops.plan_candidates(role, d, k=1 << 20)}
+    return [own, fitting.get((th, tw, t.cin_pad, narrow_out)),
+            fitting.get((th, tw, narrow_in, t.cout_pad))]
 
 
 @pytest.mark.parametrize("name", sorted(WIDE_LAYERS))
@@ -255,26 +260,107 @@ def test_wide_tiles_match_lax(name):
                 scale = float(jnp.max(jnp.abs(want[role])))
                 np.testing.assert_allclose(
                     got, want[role], rtol=0, atol=2e-5 * scale,
-                    err_msg=f"{role} {t.tile_key}")
+                    err_msg=f"{role} {_key(p)}")
     assert any(cin == 1 and cout > 1 for cin, cout in split), split
     assert any(cin > 1 for cin, _ in split), split
 
 
+def test_candidates_offer_only_dividing_channel_tiles():
+    """Every candidate's channel tiles are lane multiples that divide the
+    padded dims, and both the one-step and the 128-lane tiles are among
+    them."""
+    d = WIDE_LAYERS["3x3s2"]                  # C = 256, N = 384
+    for role in ROLES:
+        cands = ops.plan_candidates(role, d, k=1 << 20)
+        assert _key(cands[0]) == _key(_plan(role, d)), role
+        for p in cands:
+            t = _tile(p)
+            assert t.cin_tile % LANE == 0 and t.cout_tile % LANE == 0
+            assert t.cin_pad % t.cin_tile == 0, (role, _key(p))
+            assert t.cout_pad % t.cout_tile == 0, (role, _key(p))
+    tiles = {_key(p)[2:] for p in ops.plan_candidates("forward", d,
+                                                      k=1 << 20)}
+    assert {(256, 384), (128, 128)} <= tiles
+    assert not tiles & {(256, 256), (192, 384), (384, 384), (256, 64)}
+
+
 # ---------------------------------------------------------------------------
-# Persisted tiles (the autotuner's plan cache)
+# Candidate plans and the planner's clock
 # ---------------------------------------------------------------------------
 
-def test_plan_from_tile_accepts_wide_and_rejects_non_dividing_tiles():
-    d = _resnet("14x14-1x1")
-    for role in ROLES:
-        plan = _plan(role, d)
-        key = _tile(plan).tile_key
-        assert max(key[2:]) > LANE
-        assert ops.plan_from_tile(role, d, None, key) == plan, role
-    d = WIDE_LAYERS["3x3s2"]                  # C = 256, N = 384
-    th, tw, *_ = ops.forward_plan(d).tile_key
-    assert ops.plan_from_tile("forward", d, None, (th, tw, 256, 384))
-    assert ops.plan_from_tile("forward", d, None, (th, tw, 128, 128))
-    for cit, cot in ((256, 256), (192, 384), (384, 384), (256, 64)):
-        assert ops.plan_from_tile("forward", d, None,
-                                  (th, tw, cit, cot)) is None, (cit, cot)
+#: a small strided layer every role plans on the kernels.
+SMALL = ConvDims(B=1, C=4, H_i=8, W_i=8, N=4, K_h=3, K_w=3, S=2, P_h=1,
+                 P_w=1)
+
+
+def test_candidates_head_is_the_analytic_winner():
+    plan = ops.forward_plan(SMALL)
+    cands = ops.plan_candidates("forward", SMALL, k=3)
+    assert 1 <= len(cands) <= 3
+    assert _key(cands[0]) == _key(plan)
+    assert all(c.fits and c.bytes_needed <= config.vmem_budget_bytes
+               for c in cands)
+    keys = [_key(c) for c in cands]
+    assert len(set(keys)) == len(keys), f"duplicate candidates: {keys}"
+
+
+def test_candidates_cover_all_roles():
+    for role in ops.PLAN_ROLES:
+        cands = ops.plan_candidates(role, SMALL, k=2)
+        assert cands, role
+        if role == "input_grad":
+            assert all(isinstance(c, ops.PhasePlan) for c in cands)
+        else:
+            assert all(isinstance(c, ops.TilePlan) for c in cands)
+
+
+def test_unknown_role_raises():
+    with pytest.raises(ValueError, match="unknown plan role"):
+        ops.plan_candidates("sideways", SMALL)
+
+
+def test_plan_seconds_count_cold_plans_only():
+    """The planner's clock rises when a plan is searched, not when the
+    memo serves it, and resets with the plan events."""
+    assert ops.plan_seconds() == 0.0
+    ops.forward_plan(SMALL)
+    cold = ops.plan_seconds()
+    assert cold > 0
+    ops.forward_plan(SMALL)
+    assert ops.plan_seconds() == cold
+    ops.reset_plan_events()
+    assert ops.plan_seconds() == 0.0
+
+
+def test_plan_seconds_count_analytic_cache_misses():
+    ops.weight_grad_plan(SMALL)
+    cold = ops.plan_seconds()
+    assert cold > 0
+    ops.weight_grad_plan(SMALL)                  # the lru_cache holds it
+    assert ops.plan_seconds() == cold
+    ops.clear_tile_plan_cache()
+    ops.weight_grad_plan(SMALL)                  # searched again
+    assert ops.plan_seconds() > cold
+
+
+def test_every_candidate_computes_identical_results():
+    """Every candidate plan produces the same numbers (only the dispatch
+    geometry differs)."""
+    d = SMALL
+    r = np.random.RandomState(3)
+    x = jnp.asarray(r.randn(d.B, d.C, d.H_i, d.W_i), jnp.float32)
+    w = jnp.asarray(r.randn(d.N, d.C, d.K_h, d.K_w), jnp.float32)
+    dy = jnp.asarray(r.randn(d.B, d.N, d.H_o, d.W_o), jnp.float32)
+    ref_y = ref_di = ref_dw = None
+    for fwd, ig, wg in zip(ops.plan_candidates("forward", d, k=3),
+                           ops.plan_candidates("input_grad", d, k=3),
+                           ops.plan_candidates("weight_grad", d, k=3)):
+        y = ops.conv2d_forward(x, w, d, plan=fwd)
+        di = ops.conv2d_input_grad(dy, w, d, plan=ig)
+        dw = ops.conv2d_weight_grad(x, dy, d, plan=wg)
+        if ref_y is None:
+            ref_y, ref_di, ref_dw = y, di, dw
+            continue
+        np.testing.assert_allclose(y, ref_y, rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(di, ref_di, rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(dw, ref_dw, rtol=1e-4, atol=1e-4)

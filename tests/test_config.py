@@ -14,7 +14,7 @@ import warnings
 import pytest
 
 import repro
-from repro.core.config import AUTOTUNE_MODES, FIELDS, GlobalConfig, config
+from repro.core.config import FIELDS, GlobalConfig, config
 from repro.core.im2col_ref import ConvDims
 from repro.kernels import ops
 
@@ -36,9 +36,7 @@ def test_defaults_without_env():
     c = GlobalConfig(env={})
     assert c.interpret is True
     assert c.vmem_budget_bytes == 14 * 1024 * 1024
-    assert c.autotune == "off"
-    assert c.autotune_top_k == 4 and c.autotune_reps == 3
-    assert c.plan_cache_dir is None and c.remat is None
+    assert c.remat is None
     assert c.ssd_chunk == 128 and c.blockwise_kv_threshold == 1024
     assert c.scan_unroll == 1
 
@@ -46,12 +44,10 @@ def test_defaults_without_env():
 def test_env_initialization_wins_over_defaults():
     c = GlobalConfig(env={"BPIM2COL_INTERPRET": "0",
                           "REPRO_VMEM_BUDGET_BYTES": "1048576",
-                          "REPRO_AUTOTUNE": "cached",
                           "REPRO_SSD_CHUNK": "64",
                           "REPRO_REMAT": "block"})
     assert c.interpret is False
     assert c.vmem_budget_bytes == 1 << 20
-    assert c.autotune == "cached"
     assert c.ssd_chunk == 64
     assert c.remat == "block"
 
@@ -138,24 +134,19 @@ def test_unknown_field_read_and_update_raise():
 
 
 @pytest.mark.parametrize("kw", [
-    {"autotune": "sometimes"},
-    {"autotune_top_k": 0},
-    {"autotune_reps": -1},
     {"vmem_budget_bytes": "big"},
     {"interpret": "yes"},
-    {"plan_cache_dir": 7},
     {"ssd_chunk": 0},
+    {"blockwise_kv_threshold": -1},
+    {"scan_unroll": 0},
+    {"remat": 7},
+    {"fault_seed": 1.5},
+    {"telemetry": "yes"},
+    {"trace_path": 7},
 ])
 def test_update_validates(kw):
     with pytest.raises(ValueError):
         config.update(**kw)
-
-
-def test_autotune_modes_are_closed():
-    assert AUTOTUNE_MODES == ("off", "measure", "cached")
-    for mode in AUTOTUNE_MODES:
-        config.update(autotune=mode)
-        assert config.autotune == mode
 
 
 # ---------------------------------------------------------------------------
@@ -164,10 +155,12 @@ def test_autotune_modes_are_closed():
 
 def test_override_scopes_and_restores_on_exception():
     before = config.vmem_budget_bytes
-    with config.override(vmem_budget_bytes=1 << 20, autotune="cached"):
+    chunk = config.ssd_chunk
+    with config.override(vmem_budget_bytes=1 << 20, ssd_chunk=chunk + 1):
         assert config.vmem_budget_bytes == 1 << 20
-        assert config.autotune == "cached"
+        assert config.ssd_chunk == chunk + 1
     assert config.vmem_budget_bytes == before
+    assert config.ssd_chunk == chunk
     with pytest.raises(RuntimeError):
         with config.override(vmem_budget_bytes=1 << 20):
             raise RuntimeError("boom")

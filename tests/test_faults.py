@@ -1,12 +1,11 @@
-"""Fault-injection harness + runtime graceful degradation.
+"""Fault-injection harness and the recovery code it drives.
 
-Covers the full resilience stack added around the engine dispatch and the
-train loop: the config-armed injector (ft/inject.py), the execute-with-
-fallback / quarantine / probe arc in core/conv.py, plan-cache poisoning
-(kernels/autotune.py), the in-graph numerical guard in train/train_step.py,
-the loop-side GuardState escalation ladder, async-checkpoint exception
-capture, restore-with-fallback over corrupt checkpoints, heartbeat grace,
-and serve deadlines.
+Covers the config-armed injector (ft/inject.py), engine failures in the
+conv dispatch (core/conv.py: they propagate, no engine stands in), the
+in-graph numerical guard in train/train_step.py, the loop-side GuardState
+escalation ladder, async-checkpoint exception capture,
+restore-with-fallback over corrupt checkpoints, heartbeat grace, and serve
+deadlines.
 """
 
 import dataclasses
@@ -19,7 +18,7 @@ import jax.numpy as jnp
 
 from repro.core import conv
 from repro.core.config import config
-from repro.core.conv import conv2d, dispatch_events, reset_dispatch_events
+from repro.core.conv import conv2d, dispatch_events
 from repro.ckpt import checkpoint as CKPT
 from repro.ft import inject
 from repro.ft.failures import (GuardState, HeartbeatTable,
@@ -33,7 +32,7 @@ from repro.train import train_step as TS
 def _disarmed():
     """Every test starts and ends with the injector disarmed and every
     introspection surface clean (``obs.reset_all`` covers the dispatch/
-    quarantine/plan/fault counters and the obs bus)."""
+    plan/fault counters and the obs bus)."""
     from repro import obs
     saved = config.snapshot()
     config.update(fault_spec=None)
@@ -61,7 +60,7 @@ def _w():
 class TestFaultSpec:
     def test_parse_full_grammar(self):
         rules = parse_fault_spec(
-            "pallas.*:raise@step3;grad.values:nan@5;ckpt.write:raise~p0.5")
+            "serve.*:raise@step3;grad.values:nan@5;ckpt.write:raise~p0.5")
         assert [r.action for r in rules] == ["raise", "nan", "raise"]
         assert rules[0].step == 3 and rules[1].step == 5
         assert rules[2].step is None and rules[2].prob == 0.5
@@ -72,7 +71,7 @@ class TestFaultSpec:
 
     def test_unknown_action_rejected(self):
         with pytest.raises(ValueError, match="action"):
-            parse_fault_spec("pallas.*:explode")
+            parse_fault_spec("ckpt.*:explode")
 
     def test_config_validates_before_storing(self):
         with pytest.raises(ValueError):
@@ -106,177 +105,36 @@ class TestDisarmed:
 
 
 # ---------------------------------------------------------------------------
-# Runtime degradation in the dispatch layer
+# An engine that raises is surfaced, never replaced
 # ---------------------------------------------------------------------------
 
-class TestRuntimeDegradation:
-    def test_pallas_failure_degrades_to_exact_result(self):
-        x, w = _x(), _w()
-        y_ref = conv2d(x, w, stride=2, padding=1, policy="lax")
-        config.update(fault_spec="pallas.*:raise")
-        inject.set_step(0)
-        y = conv2d(x, w, stride=2, padding=1, policy="pallas")
-        np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
-                                   rtol=1e-4, atol=1e-5)
-        ev = dispatch_events()
-        assert ev.get("forward:pallas->bp_phase") == 1
-        assert ev.get("forward:bp_phase") == 1
-        rf = conv.runtime_failures()
-        assert rf and rf[0]["exception"] == "InjectedFault"
-        assert rf[0]["survivor"] == "bp_phase"
+@pytest.mark.parametrize("pass_name", ["forward", "input_grad",
+                                       "weight_grad"])
+@pytest.mark.parametrize("engine", ["pallas", "bp_phase", "lax"])
+def test_engine_failure_propagates(engine, pass_name, monkeypatch):
+    """A pass whose engine raises propagates the exception out of
+    ``conv2d`` (forward) or its VJP (the two gradients).  No other engine
+    serves the pass: every dispatch key and every policy decision recorded
+    names the requested engine, and the failed pass records neither."""
+    def broken(*a, **k):
+        raise RuntimeError(f"{engine} {pass_name} down")
 
-    def test_gradients_degrade_too(self):
-        x, w = _x(), _w()
+    monkeypatch.setitem(conv.ENGINES, engine, dataclasses.replace(
+        conv.ENGINES[engine], **{pass_name: broken}))
+    x, w = _x(), _w()
 
-        def loss(w, policy):
-            return jnp.sum(
-                conv2d(x, w, stride=2, padding=1, policy=policy) ** 2)
+    def loss(x, w):
+        return jnp.sum(conv2d(x, w, stride=2, padding=1, policy=engine) ** 2)
 
-        g_ref = jax.grad(lambda w: loss(w, "lax"))(w)
-        config.update(fault_spec="pallas.*:raise")
-        inject.set_step(0)
-        g = jax.grad(lambda w: loss(w, "pallas"))(w)
-        np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref),
-                                   rtol=1e-3, atol=1e-4)
-        ev = dispatch_events()
-        assert ev.get("input_grad:pallas->bp_phase") == 1
-        assert ev.get("weight_grad:pallas->bp_phase") == 1
-
-    def test_quarantine_skip_probe_recover(self, monkeypatch):
-        monkeypatch.setattr(conv, "QUARANTINE_PROBE_AFTER", 2)
-        x, w = _x(), _w()
-        config.update(fault_spec="pallas.forward.launch:raise@step0")
-        inject.set_step(0)
-        conv2d(x, w, stride=2, padding=1, policy="pallas")   # fails, degrades
-        assert conv.quarantined_engines()
-        config.update(fault_spec=None)
-        for step in range(1, 4):                # 2 skips, then probe
-            inject.set_step(step)
-            conv2d(x, w, stride=2, padding=1, policy="pallas")
-        ev = dispatch_events()
-        assert ev.get("forward:pallas:quarantined") == 2
-        assert ev.get("forward:pallas:probe") == 1
-        assert ev.get("forward:pallas:recovered") == 1
-        assert not conv.quarantined_engines()
-
-    def test_failed_probe_rearms_quarantine(self, monkeypatch):
-        monkeypatch.setattr(conv, "QUARANTINE_PROBE_AFTER", 1)
-        x, w = _x(), _w()
-        config.update(fault_spec="pallas.forward.launch:raise")
-        for step in range(3):                   # fail, skip, probe-fail
-            inject.set_step(step)
-            conv2d(x, w, stride=2, padding=1, policy="pallas")
-        ev = dispatch_events()
-        assert ev.get("forward:pallas:probe") == 1
-        assert "forward:pallas:recovered" not in ev
-        assert conv.quarantined_engines()       # re-armed after failed probe
-
-    def test_lax_failure_propagates(self):
-        # lax has no fault site, so fault every implicit engine and ask for
-        # an impossible run another way: all engines failing must re-raise
-        # the FIRST exception rather than silently returning garbage.
-        x, w = _x(), _w()
-        boom = RuntimeError("engine down")
-
-        def bad_engine(*a, **k):
-            raise boom
-
-        eng = dataclasses.replace(conv.ENGINES["lax"], forward=bad_engine)
-        with pytest.MonkeyPatch.context() as mp:
-            mp.setitem(conv.ENGINES, "lax", eng)
-            for name in ("bp_phase", "bp_im2col", "traditional", "pallas"):
-                mp.setitem(conv.ENGINES, name,
-                           dataclasses.replace(conv.ENGINES[name],
-                                               forward=bad_engine))
-            with pytest.raises(RuntimeError, match="engine down"):
-                conv2d(x, w, stride=2, padding=1, policy="lax")
-
-    def test_unarmed_engine_failure_propagates(self, monkeypatch):
-        """With no fault armed, an engine that raises is surfaced, never
-        served by another engine: no degradation, no quarantine."""
-        def broken(*a, **k):
-            raise RuntimeError("kernel down")
-
-        monkeypatch.setitem(conv.ENGINES, "pallas", dataclasses.replace(
-            conv.ENGINES["pallas"], forward=broken))
-        assert not config.fault_spec
-        with pytest.raises(RuntimeError, match="kernel down"):
-            conv2d(_x(), _w(), stride=2, padding=1, policy="pallas")
-        assert not any(k.startswith("forward:bp_phase")
-                       for k in dispatch_events())
-        assert not conv.runtime_failures()
-        assert not conv.quarantined_engines()
-
-    def test_reset_clears_quarantine_and_failures(self):
-        config.update(fault_spec="pallas.forward.launch:raise")
-        inject.set_step(0)
-        conv2d(_x(), _w(), stride=2, padding=1, policy="pallas")
-        assert conv.runtime_failures() and conv.quarantined_engines()
-        reset_dispatch_events()
-        assert not conv.runtime_failures()
-        assert not conv.quarantined_engines()
-
-
-# ---------------------------------------------------------------------------
-# Plan-cache poisoning
-# ---------------------------------------------------------------------------
-
-class TestPlanPoisoning:
-    def test_crashing_pallas_poisons_cached_plan(self, tmp_path):
-        from repro.kernels import autotune, ops
-        saved = config.snapshot()
-        try:
-            config.update(autotune="cached", plan_cache_dir=str(tmp_path),
-                          interpret=True)
-            ops.clear_tile_plan_cache()
-            autotune.clear_memo()
-            ops.reset_plan_events()
-            x, w = _x(), _w()
-            config.update(fault_spec="pallas.forward.launch:raise")
-            inject.set_step(0)
-            conv2d(x, w, stride=2, padding=1, policy="pallas")
-            store = autotune._load_store()
-            assert any(v.get("poisoned")
-                       for v in store["entries"].values()), store
-            # Cached mode on the poisoned key: analytic plan, counted.
-            config.update(fault_spec=None)
-            autotune.clear_memo()
-            ops.clear_tile_plan_cache()
-            reset_dispatch_events()
-            y = conv2d(x, w, stride=2, padding=1, policy="pallas")
-            assert np.isfinite(np.asarray(y)).all()
-            assert ops.plan_events().get("forward_autotune_poisoned", 0) >= 1
-        finally:
-            config.update(**saved)
-            ops.clear_tile_plan_cache()
-            autotune.clear_memo()
-            ops.reset_plan_events()
-
-    def test_measure_failure_skips_candidate(self, tmp_path):
-        from repro.kernels import autotune, ops
-        from repro.core.im2col_ref import ConvDims
-        saved = config.snapshot()
-        try:
-            config.update(autotune="measure", autotune_top_k=2,
-                          autotune_reps=1, plan_cache_dir=str(tmp_path),
-                          interpret=True)
-            autotune.clear_memo()
-            ops.reset_plan_events()
-            config.update(fault_spec="autotune.measure:raise")
-            d = ConvDims(B=1, C=4, H_i=8, W_i=8, N=4, K_h=3, K_w=3, S=2,
-                         P_h=1, P_w=1)
-            analytic = None
-            with config.override(autotune="off"):
-                analytic = ops.forward_plan(d)
-            plan = autotune.tuned_plan(
-                "forward", d, config.vmem_budget_bytes, analytic)
-            assert plan is not None        # analytic fallback, not a crash
-            assert ops.plan_events().get(
-                "forward_autotune_measure_failed", 0) >= 1
-        finally:
-            config.update(**saved)
-            autotune.clear_memo()
-            ops.reset_plan_events()
+    with pytest.raises(RuntimeError, match=f"{engine} {pass_name} down"):
+        jax.grad(loss, argnums=(0, 1))(x, w)
+    events = dispatch_events()
+    assert all(k.split(":")[1] == engine for k in events), events
+    assert not any(k.startswith(f"{pass_name}:") for k in events), events
+    decisions = conv.policy_decisions()
+    assert all(d["engine"] == engine for d in decisions), decisions
+    assert pass_name not in {d["pass"] for d in decisions}, decisions
+    assert conv.runtime_failures() == []
 
 
 # ---------------------------------------------------------------------------
@@ -482,21 +340,11 @@ def test_all_known_sites_are_exercised(tmp_path):
     site without disturbing behaviour), drive every failure domain once,
     and require full coverage of KNOWN_SITES -- a new site that is
     registered but never wired (or wired but not registered) fails here."""
-    from repro.kernels import autotune, ops
     saved = config.snapshot()
     try:
-        config.update(fault_spec="*:raise@step999999",
-                      autotune="measure", autotune_top_k=1, autotune_reps=1,
-                      plan_cache_dir=str(tmp_path), interpret=True)
+        config.update(fault_spec="*:raise@step999999")
         inject.reset_events()
-        autotune.clear_memo()
-        ops.clear_tile_plan_cache()
         inject.set_step(0)
-        # pallas launches (fwd + both grads) and the autotune read/measure/
-        # write path:
-        x, w = _x(), _w()
-        jax.grad(lambda w: jnp.sum(
-            conv2d(x, w, stride=2, padding=1, policy="pallas") ** 2))(w)
         # checkpoint write + read:
         CKPT.save(str(tmp_path / "ck"), 0, {"x": np.ones(2)})
         CKPT.restore(str(tmp_path / "ck"))
@@ -519,9 +367,6 @@ def test_all_known_sites_are_exercised(tmp_path):
         assert not missing, f"registered but never exercised: {missing}"
     finally:
         config.update(**saved)
-        autotune.clear_memo()
-        ops.clear_tile_plan_cache()
-        ops.reset_plan_events()
 
 
 # ---------------------------------------------------------------------------

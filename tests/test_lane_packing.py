@@ -17,7 +17,7 @@ import pytest
 
 from repro.core import ConvTransposeSpec, conv2d_transpose, transpose_dims
 from repro.core.im2col_ref import ConvDims
-from repro.kernels import autotune, ops
+from repro.kernels import ops
 from repro.kernels import tap_gemm as tg
 
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -209,25 +209,12 @@ def test_packed_counters_fire_exactly_when_packing_is_used(c):
     assert "input_grad_packed" not in events
 
 
-def test_packed_candidates_revalidate_through_plan_from_tile():
-    """The autotuner persists only a tile key; rebuilding it must give
-    back the packed plan, footprint and all."""
+def test_stem_candidates_all_share_the_planned_pack():
+    """Packing is fixed by the geometry, not by the tile: every candidate
+    plan of the stem carries the planner's own pack."""
     d = _dims("stem7x7s2", 3)
-    for role in ("forward", "weight_grad"):
-        for cand in ops.plan_candidates(role, d, k=3):
-            assert cand.pack is not None
-            again = ops.plan_from_tile(role, d, None, cand.tile_key)
-            assert again == cand, role
-
-
-def test_schema_2_plan_store_reads_as_empty(tmp_path):
-    """Plans gained the ``pack`` field in schema 3: a schema-2 file on
-    disk is a cold cache."""
-    assert autotune.CACHE_SCHEMA == 3
-    from repro.core.config import config
-    with config.override(plan_cache_dir=str(tmp_path)):
-        key = autotune.plan_key("forward", _dims("3x3s2", 3), 1 << 20)
-        (tmp_path / "plan_cache.json").write_text(
-            '{"schema": 2, "entries": {"%s": {"tile": [4, 8, 128, 5]}}}'
-            % key)
-        assert autotune._load_store() == {"schema": 3, "entries": {}}
+    for role, plan in (("forward", ops.forward_plan(d)),
+                       ("weight_grad", ops.weight_grad_plan(d))):
+        cands = ops.plan_candidates(role, d, k=3)
+        assert plan.pack is not None and cands[0] == plan, role
+        assert all(c.pack == plan.pack for c in cands), role

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.conv import make_dims, resolve_engine
+from repro.core.im2col_ref import ConvDims
 from repro.models import resnet as R
 from repro.models import resnet_ref as RR
 from repro.optim import adamw
@@ -204,3 +206,54 @@ def test_config_is_a_train_step_model():
     assert {"name", "conv_policy", "conv_mode"} <= {
         f.name for f in dataclasses.fields(R.ResNetConfig)}
 
+
+# ---------------------------------------------------------------------------
+# Routing of the published network's dense convs under "auto"
+# ---------------------------------------------------------------------------
+
+def _convs(cfg: R.ResNetConfig, batch: int = 32, image: int = 224):
+    """``(stage, ConvDims)`` of every bottleneck conv, in order, from
+    ``blocks(cfg)``: the stem's stride 2 and the max pool's leave a
+    quarter of the image."""
+    h = image // 4
+    for s, _, c_in, width, stride, projected in R.blocks(cfg):
+        c_out, h_out = width * R.EXPANSION, h // stride
+        layers = [(c_in, width, 1, 1, h), (width, width, 3, stride, h),
+                  (width, c_out, 1, 1, h_out)]
+        if projected:
+            layers.append((c_in, c_out, 1, stride, h))
+        for c, n, k, st, hw in layers:
+            yield s, make_dims((batch, c, hw, hw), (n, c, k, k), st, k // 2)
+        h = h_out
+
+
+def _dense_geometries() -> dict[str, ConvDims]:
+    """The distinct stride-1 geometries of ResNet-50 v1.5 at batch 32."""
+    out = {}
+    for s, d in _convs(R.ResNetConfig()):
+        if d.s_h == 1 and d not in out.values():
+            out[f"s{s}-{d.C}to{d.N}-k{d.K_h}-{d.H_i}px"] = d
+    return out
+
+
+DENSE = _dense_geometries()
+
+
+def test_dense_geometries_are_four_per_stage():
+    """46 of the 53 convs are stride 1, in 16 geometries: per stage the
+    two 1x1 reduces (the first block's at the stage's input size), the
+    3x3 and the 1x1 expand."""
+    convs = [d for _, d in _convs(R.ResNetConfig())]
+    assert len(convs) == 52                   # every conv but the stem
+    assert sum(d.s_h == 1 for d in convs) == 46
+    assert len(DENSE) == 16
+    assert [name[:2] for name in DENSE] == [f"s{s}" for s in range(4)
+                                            for _ in range(4)]
+
+
+@pytest.mark.parametrize("pass_name", ["forward", "input_grad",
+                                       "weight_grad"])
+@pytest.mark.parametrize("name", list(DENSE))
+def test_auto_sends_every_dense_pass_to_bp_phase(name, pass_name):
+    engine, reason = resolve_engine("auto", pass_name, DENSE[name])
+    assert engine == "bp_phase", reason
